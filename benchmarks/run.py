@@ -31,6 +31,8 @@ def main() -> None:
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, os.path.join(root, "src"))
     sys.path.insert(0, root)       # `import benchmarks` as a namespace pkg
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (common, dfa_throughput, elastic_recovery,
                             fig6_resources, fig8_message_rate,
                             fig9_gdr_vs_staged, gather_scaling,

@@ -16,9 +16,11 @@ from repro.compat import make_mesh
 from repro.configs import get_dfa_config
 from repro.core.pipeline import DFASystem
 from repro.data import packets as PK
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_dfa_config(reduced=True)
     system = DFASystem(cfg, mesh)

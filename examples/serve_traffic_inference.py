@@ -34,12 +34,14 @@ from repro.compat import make_mesh
 from repro.configs import get_config, get_dfa_config
 from repro.core.pipeline import DFASystem
 from repro.data import packets as PK
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import serve
 from repro.launch.serving import ServingLoop, build_source
 from repro.models.registry import get_model
 
 
 def main():
+    enable_compile_cache()
     mesh = make_mesh((1, 1), ("data", "model"))
     # arm the streaming inference hook + the serving knobs: offer events
     # 25% above the batch-capacity rate so backpressure (queueing + tail

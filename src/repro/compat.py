@@ -1,48 +1,19 @@
-"""jax API compatibility shims (pinned jax 0.4.37 vs newer releases).
+"""Mesh construction shared by the library, tests, benchmarks and examples.
 
-Three spellings changed between the pinned jax and current releases; every
-call site in this repo goes through this module so the code runs on both:
-
-* ``make_mesh`` — the ``axis_types=(AxisType.Auto, ...)`` kwarg (and
-  ``jax.sharding.AxisType`` itself) only exist from jax 0.5+.
-* ``shard_map`` — new jax exposes ``jax.shard_map(..., check_vma=)``;
-  0.4.x has ``jax.experimental.shard_map.shard_map(..., check_rep=)``.
-* ``axis_size`` — ``jax.lax.axis_size`` is new; ``psum(1, name)`` is the
-  portable equivalent inside a mapped context.
+``make_mesh`` builds every mesh with explicit ``AxisType.Auto`` axes, so
+sharding stays with the compiler (GSPMD propagation) whatever the default
+axis type of the installed jax is.
 """
 from __future__ import annotations
 
 from typing import Any, Optional, Sequence
 
 import jax
-
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices: Optional[Sequence[Any]] = None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if _AXIS_TYPE is not None:
-        return jax.make_mesh(
-            axis_shapes, axis_names, devices=devices,
-            axis_types=(_AXIS_TYPE.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` (check_vma=) or the 0.4.x experimental equivalent
-    (check_rep=); ``check`` maps onto whichever knob exists."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check)
-
-
-def axis_size(name):
-    """Size of a mapped mesh axis, usable inside shard_map bodies."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    # psum of the literal 1 is constant-folded to the axis size at trace time
-    return jax.lax.psum(1, name)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(axis_shapes, axis_names, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axis_names))

@@ -166,7 +166,7 @@ class DFAConfig:
     # names fail loud at DFASystem construction.
     wire_format: str = "v1"
     # gather_enrich memory strategy: "auto" | "full" (ring region pinned
-    # in VMEM) | "hbm" (ring stays HBM-resident, per-report-tile DMA).
+    # in VMEM) | "hbm" (ring stays HBM-resident, XLA gathers the rows).
     # auto = VMEM-budget heuristic in dispatch.resolve_gather_variant;
     # REPRO_GATHER_VARIANT env var overrides this field.
     gather_variant: str = "auto"

@@ -3,11 +3,10 @@
 PAPER      — faithful Tofino-scale config: 2^17 flows/shard, 10-entry ring,
              64 B payload, 20 ms monitoring period. At this scale the ring
              region is ~84 MB/shard, so gather_variant="auto" resolves to
-             the HBM-resident tiled kernel (ring stays in HBM, VMEM holds
-             only double-buffered report tiles).
-REDUCED    — CPU-testable miniature with the same structure; its ~170 KB
-             ring region fits VMEM, so auto resolves to the full-block
-             kernel.
+             the HBM-resident path (ring stays in HBM, XLA gathers the
+             routed rows, VMEM holds only double-buffered report tiles).
+REDUCED    — CPU-testable miniature with the same structure; its ring
+             region fits VMEM, so auto resolves to the full kernel.
 """
 import dataclasses
 
@@ -35,7 +34,7 @@ REDUCED = DFAConfig(
 )
 
 # REDUCED shapes forced onto the Tofino-scale memory strategy: the
-# equivalence suite / benchmarks use this to exercise the HBM-tiled path
+# equivalence suite / benchmarks use this to exercise the HBM-resident path
 # without allocating a 2^17-flow ring.
 REDUCED_HBM = dataclasses.replace(REDUCED, gather_variant="hbm")
 

@@ -177,8 +177,8 @@ KERNEL_BACKEND = register(EnvSpec(
 
 GATHER_VARIANT = register(EnvSpec(
     "REPRO_GATHER_VARIANT", "choice", ("full", "hbm"),
-    description="gather_enrich memory strategy (full-block VMEM vs "
-                "HBM-resident tiled DMA)",
+    description="gather_enrich memory strategy (ring pinned in VMEM vs "
+                "HBM-resident ring, routed rows gathered by XLA)",
     consumer="repro.kernels.dispatch"))
 
 INGEST_VARIANT = register(EnvSpec(

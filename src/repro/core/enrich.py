@@ -23,16 +23,28 @@ EPS = 1e-6
 PER_ENTRY = 18            # features derived per history entry
 
 
-def entry_features(stats_u32: jax.Array) -> jax.Array:
-    """(…, 7) u32 Table-I registers -> (…, PER_ENTRY) f32 derived features.
+def u32_to_f32(x: jax.Array) -> jax.Array:
+    """u32 -> f32 without an unsigned-to-float conversion (Mosaic has
+    none). The high half times 2^16 is exact, so the sum rounds once and
+    matches a direct cast bit for bit."""
+    x = x.astype(jnp.uint32)
+    hi = (x >> 16).astype(jnp.int32).astype(jnp.float32)
+    lo = (x & jnp.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    return hi * 65536.0 + lo
+
+
+def entry_feature_list(s) -> list:
+    """Seven f32 Table-I register arrays (any common shape) -> the
+    PER_ENTRY derived features, as a list of arrays of that shape.
 
     Moment identities: mean = S1/n, var = S2/n - mean², skew via S3
-    (all on the log*-approximated sums, like Marina's CPU stage).
+    (all on the log*-approximated sums, like Marina's CPU stage). Shared
+    by :func:`entry_features` and the Pallas kernel, which works on one
+    (H, T) plane per register.
     """
-    s = stats_u32.astype(jnp.float32)
-    n = jnp.maximum(s[..., 0], 1.0)
-    iat1, iat2, iat3 = s[..., 1], s[..., 2], s[..., 3]
-    ps1, ps2, ps3 = s[..., 4], s[..., 5], s[..., 6]
+    n = jnp.maximum(s[0], 1.0)
+    iat1, iat2, iat3 = s[1], s[2], s[3]
+    ps1, ps2, ps3 = s[4], s[5], s[6]
 
     def moments(s1, s2, s3):
         mean = s1 / n
@@ -49,12 +61,18 @@ def entry_features(stats_u32: jax.Array) -> jax.Array:
     volume = ps1                                         # bytes
     rate_bps = volume * 8.0 / (duration / 1e6 + EPS)
     pps = n / (duration / 1e6 + EPS)
-    return jnp.stack([
-        n, i_mean, i_var, i_std, i_cov, i_skew,
-        p_mean, p_var, p_std, p_cov, p_skew,
-        volume, rate_bps, pps, duration,
-        jnp.log1p(volume), jnp.log1p(rate_bps), jnp.log1p(n),
-    ], axis=-1)
+    return [n, i_mean, i_var, i_std, i_cov, i_skew,
+            p_mean, p_var, p_std, p_cov, p_skew,
+            volume, rate_bps, pps, duration,
+            jnp.log1p(volume), jnp.log1p(rate_bps), jnp.log1p(n)]
+
+
+def entry_features(stats_u32: jax.Array) -> jax.Array:
+    """(…, 7) u32 Table-I registers -> (…, PER_ENTRY) f32 derived
+    features (:func:`entry_feature_list`, stacked on the last axis)."""
+    s = u32_to_f32(stats_u32)
+    return jnp.stack(entry_feature_list([s[..., k] for k in range(7)]),
+                     axis=-1)
 
 
 def derive_ref(memory_entries: jax.Array, entry_valid: jax.Array,
@@ -100,18 +118,19 @@ def derive_ref(memory_entries: jax.Array, entry_valid: jax.Array,
 def enrich_history(memory: jax.Array, entry_valid: jax.Array,
                    local_flow: jax.Array, cfg: DFAConfig, mask=None,
                    backend=None, variant=None) -> jax.Array:
-    """Selector-routed fused gather + derivation: the public enrichment
-    entry point. (F, H, 16) ring memory + (F, H) validity + (R,) local
-    flow ids -> (R, derived_dim) f32.
+    """Selector-routed gather + derivation: the public enrichment entry
+    point. (F, H, 16) ring memory + (F, H) validity + (R,) local flow ids
+    -> (R, derived_dim) f32.
 
     Routes through the gather_enrich dispatch family — backend per
     ``DFAConfig.kernel_backend`` / ``REPRO_KERNEL_BACKEND``, memory
-    strategy (full-block VMEM vs HBM-resident tiled) per
-    ``DFAConfig.gather_variant`` / ``REPRO_GATHER_VARIANT`` / the
-    VMEM-budget heuristic. Never materializes the (R, H, 16) gather.
+    strategy (``full``: ring pinned in VMEM, gathered in the kernel;
+    ``hbm``: XLA gathers the R routed rows, the derive kernel streams
+    them) per ``DFAConfig.gather_variant`` / ``REPRO_GATHER_VARIANT`` /
+    the VMEM-budget heuristic.
 
     ``mask`` (optional (R,) bool — the routed-report validity from the
-    ingest half) zeroes masked-out output rows after the fused kernel.
+    ingest half) zeroes masked-out output rows after the kernel.
     """
     from repro.kernels.gather_enrich.ops import gather_enrich  # no cycle
     out = gather_enrich(memory, entry_valid, local_flow, cfg,

@@ -8,6 +8,9 @@ LUT (the match-action analogue), exp2 through the inverse LUT. All state is
 uint32 with natural mod-2^32 wraparound — the P4 register semantics.
 
 Functions are pure jnp (usable inside Pallas kernels and as the oracle).
+The ``*_with_lut`` variants take a ``take(lut, idx)`` LUT reader: the
+default is a plain gather, which XLA lowers and Mosaic does not; kernel
+bodies pass :func:`take_onehot` instead.
 """
 from __future__ import annotations
 
@@ -34,15 +37,41 @@ def _luts(bits: int):
     return log_lut, exp_lut
 
 
-def log2_star_with_lut(x: jax.Array, bits: int,
-                       lut: jax.Array) -> jax.Array:
+def take_gather(lut: jax.Array, idx: jax.Array) -> jax.Array:
+    """``lut[idx]`` as a gather (the XLA path)."""
+    return lut[idx]
+
+
+def take_onehot(lut: jax.Array, idx: jax.Array) -> jax.Array:
+    """``lut[idx]`` without a gather, for Mosaic kernel bodies.
+
+    ``lut`` is the table as an (n, 1) f32 column, ``idx`` a (1, T) row of
+    indices in [0, n). Each lane selects its entry through an (n, T)
+    one-hot mask and sums down the sublanes. Exact: every LUT entry is
+    below 2^24, so it is an f32 integer, and the sum has one nonzero term.
+    Returns a (1, T) u32 row."""
+    n = lut.shape[0]
+    hot = jax.lax.broadcasted_iota(jnp.int32, (n, idx.shape[-1]), 0) == (
+        idx.astype(jnp.int32))
+    val = jnp.sum(jnp.where(hot, lut, 0.0), axis=0, keepdims=True)
+    return val.astype(jnp.int32).astype(jnp.uint32)
+
+
+def lut_column(lut) -> jax.Array:
+    """An integer LUT as the (n, 1) f32 column :func:`take_onehot` reads."""
+    return jnp.asarray(np.asarray(lut, np.float32).reshape(-1, 1))
+
+
+def log2_star_with_lut(x: jax.Array, bits: int, lut: jax.Array,
+                       take=take_gather) -> jax.Array:
     """:func:`log2_star` with the LUT passed explicitly — for Pallas
     kernel bodies, where a captured jnp constant is illegal and the LUT
-    must arrive as a kernel input."""
+    must arrive as a kernel input (read through ``take``)."""
     x = x.astype(jnp.uint32)
     # exponent = position of the leading set bit (31 - clz), on u32 so the
     # top bit (x >= 2^31) is handled correctly
-    nbits = (32 - jax.lax.clz(jnp.maximum(x, jnp.uint32(1)))).astype(
+    # (a select, not an unsigned max: Mosaic has no unsigned max)
+    nbits = (32 - jax.lax.clz(jnp.where(x == 0, jnp.uint32(1), x))).astype(
         jnp.int32)
     e = (nbits - 1).astype(jnp.uint32)                     # floor(log2 x)
     # top `bits` mantissa bits below the leading bit
@@ -51,7 +80,7 @@ def log2_star_with_lut(x: jax.Array, bits: int,
     # if the value has fewer than `bits` mantissa bits, scale up
     upshift = jnp.maximum(bits - (nbits - 1), 0).astype(jnp.uint32)
     frac_bits = (frac_bits << upshift) & ((1 << bits) - 1)
-    val = (e << Q) + lut[frac_bits]
+    val = (e << Q) + take(lut, frac_bits)
     return jnp.where(x == 0, jnp.uint32(0), val.astype(jnp.uint32))
 
 
@@ -60,13 +89,13 @@ def log2_star(x: jax.Array, bits: int) -> jax.Array:
     return log2_star_with_lut(x, bits, jnp.asarray(_luts(bits)[0]))
 
 
-def exp2_star_with_lut(l: jax.Array, bits: int,
-                       lut: jax.Array) -> jax.Array:
+def exp2_star_with_lut(l: jax.Array, bits: int, lut: jax.Array,
+                       take=take_gather) -> jax.Array:
     """:func:`exp2_star` with the LUT passed explicitly (Pallas-safe)."""
     l = l.astype(jnp.uint32)
     e = (l >> Q).astype(jnp.int32)                         # integer part
     frac = ((l >> (Q - bits)) & ((1 << bits) - 1)).astype(jnp.uint32)
-    mant = (jnp.uint32(1) << jnp.uint32(bits)) + lut[frac]  # in [2^b, 2^{b+1})
+    mant = (jnp.uint32(1) << jnp.uint32(bits)) + take(lut, frac)  # [2^b, 2^{b+1})
     sat = e >= 32                       # [2^31, 2^32) is still representable
     sh = jnp.clip(e - bits, -(bits + 32), 31)
     down = jnp.clip(-sh, 1, 31).astype(jnp.uint32)
@@ -85,15 +114,15 @@ def exp2_star(l: jax.Array, bits: int) -> jax.Array:
 
 
 def approx_pow_with_luts(x: jax.Array, n: int, bits: int,
-                         log_lut: jax.Array,
-                         exp_lut: jax.Array) -> jax.Array:
+                         log_lut: jax.Array, exp_lut: jax.Array,
+                         take=take_gather) -> jax.Array:
     """:func:`approx_pow` with both LUTs passed explicitly (Pallas-safe:
     kernel bodies feed the LUT refs they received as inputs)."""
-    lx = log2_star_with_lut(x, bits, log_lut)
+    lx = log2_star_with_lut(x, bits, log_lut, take)
     ln = lx * jnp.uint32(n)
     # detect overflow of the power before exp
     sat = (ln >> Q) >= 32
-    v = exp2_star_with_lut(ln, bits, exp_lut)
+    v = exp2_star_with_lut(ln, bits, exp_lut, take)
     v = jnp.where(sat, jnp.uint32(0xFFFFFFFF), v)
     return jnp.where(x == 0, jnp.uint32(0), v)
 

@@ -27,10 +27,11 @@ One monitoring period is two explicit half-steps:
   ``ingest_half``  — reporter ingest, due-flow reports, all_to_all
                      routing, translator addressing, ring placement;
                      returns the period's :class:`RoutedBatch` coords
-  ``enrich_half``  — fused gather+enrich of those routed flows (plus the
-                     optional immediate-inference hook: a model head from
-                     ``models.registry.get_flow_head`` consuming the
-                     (R, derived_dim) features in the same trace)
+  ``enrich_half``  — history gather + enrichment of those routed flows
+                     (plus the optional immediate-inference hook: a
+                     model head from ``models.registry.get_flow_head``
+                     consuming the (R, derived_dim) features in the
+                     same trace)
 
 ``run_periods`` chains both halves per period under one ``lax.scan``;
 ``run_periods_overlapped`` software-pipelines the stream — the carry holds
@@ -69,7 +70,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
 from repro.configs.base import DFAConfig
 from repro.core import collector as COLL
 from repro.core import protocol as PROTO
@@ -414,7 +414,7 @@ class DFASystem:
         def local(rep_st, tr_st, coll_st, ev_ts, ev_sz, ev_tu, ev_va, now_):
             shard = jnp.zeros((), jnp.int32)
             for a in ax:
-                shard = shard * axis_size(a) + jax.lax.axis_index(a)
+                shard = shard * jax.lax.axis_size(a) + jax.lax.axis_index(a)
             flow_base = shard * cfg.flows_per_shard
             # cumulative counters BEFORE this period (for metric deltas)
             collisions0 = jnp.sum(rep_st.collisions)
@@ -494,13 +494,13 @@ class DFASystem:
         specs = self.state_specs()
         ev_specs = (P(ax), P(ax), P(ax, None), P(ax))
         out_state_specs = (specs.reporter, specs.translator, specs.collector)
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(specs.reporter, specs.translator, specs.collector)
             + ev_specs + (P(),),
             out_specs=out_state_specs
             + (P(ax), P(ax), P(ax), self._metric_specs(ax)),
-            check=False)
+            check_vma=False)
         rep_st, tr_st, coll_st, local_flow, flow_id, rmask, metrics = fn(
             state.reporter, state.translator, state.collector,
             events["ts"], events["size"], events["five_tuple"],
@@ -573,7 +573,7 @@ class DFASystem:
                 pod = jnp.zeros((), jnp.int32)
             sp = jnp.zeros((), jnp.int32)
             for a in self.shard_axes:
-                sp = sp * axis_size(a) + jax.lax.axis_index(a)
+                sp = sp * jax.lax.axis_size(a) + jax.lax.axis_index(a)
             dev = pod * S + sp
             if hrw:
                 # flow ids encode the stable node id, not the position
@@ -771,13 +771,13 @@ class DFASystem:
         ev_specs = (P(ax), P(ax), P(ax, None), P(ax))
         out_state_specs = (specs.reporter, specs.translator,
                            specs.collector)
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(specs.reporter, specs.translator, specs.collector)
             + ev_specs + (P(),),
             out_specs=out_state_specs
             + (P(ax), P(ax), P(ax), self._metric_specs(ax)),
-            check=False)
+            check_vma=False)
         rep_st, tr_st, coll_st, local_flow, flow_id, rmask, metrics = fn(
             state.reporter, state.translator, state.collector,
             events["ts"], events["size"], events["five_tuple"],
@@ -786,12 +786,12 @@ class DFASystem:
                 RoutedBatch(local_flow, flow_id, rmask), metrics)
 
     def enrich_half(self, state: DFAState, routed: RoutedBatch):
-        """Second half of a monitoring period: fused gather + enrichment
-        of the routed flows (via dispatch; skips the (R, H, 16) history
-        materialization; the op owns the [0, F) clamp of local_flow and
-        the memory-strategy choice — full-block VMEM at reduced F,
-        HBM-tiled at Tofino scale), plus the optional immediate-inference
-        hook on the resulting features.
+        """Second half of a monitoring period: history gather +
+        enrichment of the routed flows (via dispatch; the op owns the
+        [0, F) clamp of local_flow and the memory-strategy choice — the
+        ring pinned in VMEM while it fits, else an XLA gather of the R
+        routed rows feeding the derive kernel), plus the optional
+        immediate-inference hook on the resulting features.
 
         Reads the collector ring, never writes it — which is what makes
         it legal to defer one period in the overlapped driver. Returns
@@ -807,10 +807,10 @@ class DFASystem:
             return enriched, flow_ids, m
 
         specs = self.state_specs()
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(specs.collector, P(ax), P(ax), P(ax)),
-            out_specs=(P(ax, None), P(ax), P(ax)), check=False)
+            out_specs=(P(ax, None), P(ax), P(ax)), check_vma=False)
         enriched, flow_ids, emask = fn(state.collector, routed.local_flow,
                                        routed.flow_id, routed.mask)
         preds = None

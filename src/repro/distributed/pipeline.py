@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 
 Tree = Any
 
@@ -90,10 +89,10 @@ def pipeline_apply(stage_fn: Callable[[Tree, jax.Array, jax.Array],
             jnp.where(sid == S - 1, outs, jnp.zeros_like(outs)), axis)
         return outs.reshape(xl.shape)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params),
                   P(other_axes or None)),
         out_specs=P(other_axes or None),
-        check=False)
+        check_vma=False)
     return fn(stage_params, x)

@@ -2,69 +2,125 @@
 
 The paper runs Marina's ~100 derived-feature computation "on CUDA cores";
 here one VPU-bound Pallas kernel decodes the Table-I moment registers of a
-(flow_tile, history, 16-word) collector tile into the derived feature block
-(flow_tile, derived_dim). All selection (newest entry) is done with
-iota/one-hot — no gathers. The math is identical to
-repro.core.enrich (the jnp oracle).
+tile of T flows into their derived feature block. The math is identical
+to repro.core.enrich (the jnp oracle).
+
+Layout. Flows ride the lanes: the kernel reads the collector entries as a
+word-major (W*H, T) block (row w*H + h = word w of history entry h) and
+writes a (derived_dim, T) block, which the wrapper transposes back to
+(T, derived_dim). Every quantity is then an (H, T) plane or a (1, T) row,
+which Mosaic lowers without relayouts; the natural (T, H, 16) tile would
+pad its 16-word minor dim to 128 lanes. All selection (newest entry) is
+iota/one-hot — no gathers — and every reduction over the history adds
+rows in order h = 0..H-1, the order XLA's CPU reduce uses, so on the CPU
+the kernel and the oracle agree bit for bit.
 """
 from __future__ import annotations
 
 import functools
+from typing import Callable, List
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import wire as WIRE
-from repro.core.enrich import PER_ENTRY, entry_features
+from repro.core.enrich import entry_feature_list, u32_to_f32
 
 WORDS = 16
 
 
-def derive_block(entries: jax.Array, valid: jax.Array,
-                 derived_dim: int,
-                 wire: WIRE.WireFormat = WIRE.V1) -> jax.Array:
-    """(T, H, 16) u32 entries + (T, H) bool -> (T, derived_dim) f32.
+def _hreduce(fn, x: jax.Array) -> jax.Array:
+    """(H, T) -> (1, T): ``fn`` folded over the rows in order h = 0..H-1."""
+    acc = x[0:1]
+    for h in range(1, x.shape[0]):
+        acc = fn(acc, x[h:h + 1])
+    return acc
 
-    The feature math shared by this kernel and the fused gather_enrich
-    kernel; all selection (newest entry) is iota/one-hot — no gathers —
-    and the hist_idx decode comes off the wire schema's Field helpers
-    (plain u32 bit ops), so it lowers cleanly inside any Pallas body.
-    Mirrors repro.core.enrich.derive_ref.
+
+def _hsum(x: jax.Array) -> jax.Array:
+    return _hreduce(jnp.add, x)
+
+
+def derive_rows(word: Callable[[int], jax.Array], valid: jax.Array,
+                derived_dim: int,
+                wire: WIRE.WireFormat = WIRE.V1) -> List[jax.Array]:
+    """The feature math shared by this kernel and gather_enrich.
+
+    ``word(w)`` returns word w of every history entry as an (H, T) u32
+    plane; ``valid`` is the (H, T) entry validity. Returns derived_dim
+    (1, T) f32 rows. Mirrors repro.core.enrich.derive_ref: newest entry's
+    PER_ENTRY | window mean | window std | newest - mean | nvalid |
+    max hist index | zero pad.
     """
-    T, H, _ = entries.shape
-    stats = entries[:, :, wire.payload_stats_slice].astype(jnp.uint32)
-    hist_idx = wire.payload_hist.extract(entries).astype(jnp.float32)
-    feats = entry_features(stats)                    # (T, H, PER_ENTRY)
-    vmask = valid.astype(jnp.float32)[..., None]
-    feats = feats * vmask
-    nvalid = jnp.maximum(valid.sum(-1, keepdims=True), 1).astype(
-        jnp.float32)                                 # (T, 1)
-    count = jnp.where(valid, stats[..., 0], 0)       # (T, H)
-    newest = jnp.argmax(count, axis=-1)              # (T,)
-    sel = (jax.lax.broadcasted_iota(jnp.int32, (T, H), 1)
-           == newest[:, None]).astype(jnp.float32)   # (T, H) one-hot
-    newest_f = jnp.sum(feats * sel[..., None], axis=1)       # (T, PER_ENTRY)
-    mean_w = feats.sum(1) / nvalid
+    H, T = valid.shape
+    stats = [u32_to_f32(word(w)) for w in range(*wire.payload_stats)]
+    hist = wire.payload_hist.get(word(wire.payload_hist.word)).astype(
+        jnp.int32).astype(jnp.float32)            # < 2^16: exact via i32
+    vmask = jnp.where(valid, 1.0, 0.0)
+    feats = [f * vmask for f in entry_feature_list(stats)]
+    nvalid = jnp.maximum(_hsum(valid.astype(jnp.int32)), 1).astype(
+        jnp.float32)                              # (1, T)
+    # newest = first entry with the largest packet count (argmax), from
+    # int32 ops: Mosaic's argmax is f32-only and it has no unsigned
+    # compares, so flip the sign bit, which maps u32 order onto i32 order
+    count = jnp.where(valid, word(wire.payload_stats[0]), jnp.uint32(0))
+    key = (count ^ jnp.uint32(0x80000000)).astype(jnp.int32)
+    top = _hreduce(jnp.maximum, key)
+    hpos = jax.lax.broadcasted_iota(jnp.int32, (H, T), 0)
+    newest = _hreduce(jnp.minimum, jnp.where(key == top, hpos, H))
+    sel = jnp.where(hpos == newest, 1.0, 0.0)     # (H, T) one-hot
+    newest_f = [_hsum(f * sel) for f in feats]
+    mean_w = [_hsum(f) / nvalid for f in feats]
     # two-pass (masked) variance — same formulation as enrich.derive_ref
-    dev = (feats - mean_w[:, None, :]) * vmask
-    var_w = (dev * dev).sum(1) / nvalid
-    std_w = jnp.sqrt(var_w)
-    delta = newest_f - mean_w
-    maxhist = jnp.max(jnp.where(valid, hist_idx, 0.0), axis=-1,
-                      keepdims=True)
-    out = jnp.concatenate([newest_f, mean_w, std_w, delta, nvalid,
-                           maxhist], axis=-1)
-    D = out.shape[-1]
-    if D < derived_dim:
-        out = jnp.pad(out, ((0, 0), (0, derived_dim - D)))
-    return out[:, :derived_dim]
+    std_w = []
+    for f, m in zip(feats, mean_w):
+        dev = (f - m) * vmask
+        std_w.append(jnp.sqrt(_hsum(dev * dev) / nvalid))
+    delta = [n - m for n, m in zip(newest_f, mean_w)]
+    maxhist = _hreduce(jnp.maximum, jnp.where(valid, hist, 0.0))
+    rows = newest_f + mean_w + std_w + delta + [nvalid, maxhist]
+    zero = jnp.zeros((1, T), jnp.float32)
+    rows += [zero] * (derived_dim - len(rows))
+    return rows[:derived_dim]
 
 
-def _kernel(entries_ref, valid_ref, out_ref, *, derived_dim: int,
-            wire: WIRE.WireFormat):
-    out_ref[...] = derive_block(entries_ref[...], valid_ref[...] > 0,
-                                derived_dim, wire=wire)
+def write_rows(out_ref, rows: List[jax.Array]) -> None:
+    """Store (1, T) rows into a (len(rows), T) output block."""
+    for j, row in enumerate(rows):
+        out_ref[pl.ds(j, 1), :] = row
+
+
+def _kernel(ent_ref, valid_ref, out_ref, *, history: int,
+            derived_dim: int, wire: WIRE.WireFormat):
+    H = history
+    write_rows(out_ref, derive_rows(
+        lambda w: ent_ref[pl.ds(w * H, H), :], valid_ref[...] > 0,
+        derived_dim, wire))
+
+
+def derive_pallas(entries: jax.Array, valid: jax.Array, *,
+                  derived_dim: int, flow_tile: int, interpret: bool,
+                  wire: WIRE.WireFormat, name: str) -> jax.Array:
+    """The lane-dense derivation kernel over (F, H, 16) entries, tiled by
+    ``flow_tile`` flows; ``name`` labels the kernel in the compiled HLO."""
+    F, H, W = entries.shape
+    assert F % flow_tile == 0 and W == WORDS, (F, flow_tile, W)
+    planes = entries.transpose(2, 1, 0).reshape(W * H, F)   # word-major
+    out = pl.pallas_call(
+        functools.partial(_kernel, history=H, derived_dim=derived_dim,
+                          wire=wire),
+        grid=(F // flow_tile,),
+        in_specs=[
+            pl.BlockSpec((W * H, flow_tile), lambda f: (0, f)),
+            pl.BlockSpec((H, flow_tile), lambda f: (0, f)),
+        ],
+        out_specs=pl.BlockSpec((derived_dim, flow_tile), lambda f: (0, f)),
+        out_shape=jax.ShapeDtypeStruct((derived_dim, F), jnp.float32),
+        interpret=interpret,
+        name=name,
+    )(planes, valid.astype(jnp.int32).T)
+    return out.T
 
 
 @functools.partial(jax.jit,
@@ -75,17 +131,6 @@ def derived_features_pallas(entries: jax.Array, valid: jax.Array,
                             interpret: bool = True,
                             wire: WIRE.WireFormat = WIRE.V1) -> jax.Array:
     """entries: (F, H, 16) u32; valid: (F, H) bool -> (F, derived_dim) f32."""
-    F, H, W = entries.shape
-    assert F % flow_tile == 0 and W == WORDS
-
-    return pl.pallas_call(
-        functools.partial(_kernel, derived_dim=derived_dim, wire=wire),
-        grid=(F // flow_tile,),
-        in_specs=[
-            pl.BlockSpec((flow_tile, H, WORDS), lambda f: (f, 0, 0)),
-            pl.BlockSpec((flow_tile, H), lambda f: (f, 0)),
-        ],
-        out_specs=pl.BlockSpec((flow_tile, derived_dim), lambda f: (f, 0)),
-        out_shape=jax.ShapeDtypeStruct((F, derived_dim), jnp.float32),
-        interpret=interpret,
-    )(entries, valid.astype(jnp.int32))
+    return derive_pallas(entries, valid, derived_dim=derived_dim,
+                         flow_tile=flow_tile, interpret=interpret,
+                         wire=wire, name="derived_features")

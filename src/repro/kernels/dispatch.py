@@ -12,6 +12,9 @@ Families shipped here: ``flow_moments`` (reporter accumulate),
 ``gather_enrich`` (fused history-gather + enrichment) and
 ``flash_attention`` (model serving path).
 
+``pallas`` runs only on a TPU: requesting it anywhere else raises (see
+:func:`interpret_flag`); ``interpret`` is the CPU route.
+
 Backend selection precedence (strongest first):
 
 1. an explicit ``backend=`` argument at the call site (``"auto"`` defers)
@@ -26,16 +29,17 @@ lose to the stronger setting.
 
 ``gather_enrich`` additionally carries a memory-strategy *variant*: the
 ``full`` kernel pins the shard's whole (F, H, 16) ring region in VMEM,
-the ``hbm`` kernel keeps it HBM-resident and DMAs per-report tiles into
-double-buffered scratch. ``resolve_gather_variant`` picks one by a
-VMEM-budget heuristic (full while the ring region fits, hbm beyond),
-overridable via ``DFAConfig.gather_variant`` or ``REPRO_GATHER_VARIANT``.
+the ``hbm`` kernel keeps it HBM-resident and reads only the routed rows.
+``resolve_gather_variant`` picks one by a VMEM-budget heuristic (full
+while the ring region fits, hbm beyond), overridable via
+``DFAConfig.gather_variant`` or ``REPRO_GATHER_VARIANT``. Fitting is not
+speed: no chip run has yet compared the two variants.
 
 ``ingest_update`` (reporter-side fused sort-once / segment-reduce ingest)
 mirrors that scheme on the *event* axis: the ``block`` kernel streams the
 sorted event arrays through BlockSpec-tiled VMEM blocks, the ``hbm``
-kernel keeps them HBM-resident (``pltpu.ANY``) and double-buffers
-per-``event_tile`` DMA slices with scalar-prefetched run-boundary
+kernel keeps them HBM-resident (``pl.ANY``) and double-buffers DMA
+slices of 8 ``event_tile`` rows with scalar-prefetched run-boundary
 metadata, so events_per_shard can grow to 2^20 with VMEM = O(event_tile).
 ``resolve_ingest_variant`` picks block while the whole sorted stream fits
 the VMEM budget, overridable via ``DFAConfig.ingest_variant`` or
@@ -54,7 +58,7 @@ until re-traced (jit caches are keyed on shapes, not on this env var).
 """
 from __future__ import annotations
 
-import warnings
+import re
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -71,6 +75,7 @@ INGEST_ENV_VAR = ENV.INGEST_VARIANT.name
 WORDS = 16               # collector entry words (64 B RoCEv2 payload)
 EVENT_WORDS = 5          # sorted-event-stream words: slot/ts/ps/base_ts/first
 VMEM_BYTES_PER_MB = 1 << 20
+SUBLANES, LANES = 8, 128  # Mosaic's 32-bit VMEM tile
 
 _REGISTRY: Dict[str, Dict[str, Callable]] = {}
 _BUILTIN_LOADED = False
@@ -205,10 +210,16 @@ def resolve_report_tile(cfg, reports: int) -> int:
 
 # -- gather_enrich memory-strategy variant ----------------------------------
 
+def _pad(n: int, multiple: int) -> int:
+    return -(-int(n) // multiple) * multiple
+
+
 def ring_vmem_bytes(flows: int, history: int, words: int = WORDS) -> int:
-    """VMEM the full-block gather_enrich kernel pins for the shard ring
-    region: (F, H, words) u32 entries + (F, H) i32 validity."""
-    return flows * history * (words * 4 + 4)
+    """VMEM the full gather_enrich kernel pins for the shard ring region:
+    (F, words*H) u32 word-major rows + (F, H) i32 validity, each minor dim
+    padded to whole 128-lane tiles, double-buffered by the pipeline."""
+    return 2 * 4 * _pad(flows, SUBLANES) * (
+        _pad(words * history, LANES) + _pad(history, LANES))
 
 
 def gather_vmem_bytes(variant: str, flows: int, history: int,
@@ -216,16 +227,21 @@ def gather_vmem_bytes(variant: str, flows: int, history: int,
                       words: int = WORDS) -> int:
     """Estimated peak VMEM working set of one gather_enrich variant.
 
-    full: whole ring region + one report-tile scratch pair + out tile.
-    hbm:  two double-buffered report-tile scratch pairs + out tile —
-          independent of F (the ring region stays in HBM).
+    full: the pinned ring region, the (T, words*H) + (T, H) row scratch
+          and the double-buffered (derived_dim, T) output block.
+    hbm:  XLA gathers the R routed rows in HBM; the derive kernel streams
+          double-buffered (words*H, T) entry, (H, T) validity and
+          (derived_dim, T) output blocks — independent of F.
     """
-    tile = report_tile * history * (words * 4 + 4)   # entries + validity
-    out = report_tile * derived_dim * 4
+    lanes = _pad(report_tile, LANES)
+    out = 2 * 4 * _pad(derived_dim, SUBLANES) * lanes
     if variant == "full":
-        return ring_vmem_bytes(flows, history, words) + tile + out
+        scratch = 4 * _pad(report_tile, SUBLANES) * (
+            _pad(words * history, LANES) + _pad(history, LANES))
+        return ring_vmem_bytes(flows, history, words) + scratch + out
     if variant == "hbm":
-        return 2 * tile + out
+        return 2 * 4 * lanes * (_pad(words * history, SUBLANES)
+                                + _pad(history, SUBLANES)) + out
     raise ValueError(f"unknown gather variant {variant!r}; "
                      f"registered: {list(GATHER_VARIANTS)}")
 
@@ -313,20 +329,32 @@ def resolve_ingest_variant(variant: Optional[str], cfg, events: int,
 
 
 def interpret_flag(backend: str) -> bool:
-    """Whether a Pallas impl must run interpreted (also forced off-TPU, so a
-    'pallas' request never feeds Mosaic a CPU target). The downgrade is
-    loud: interpreter-mode timings must never be mistaken for compiled
-    pallas numbers."""
+    """Whether a Pallas impl runs in the interpreter: exactly for the
+    ``interpret`` backend. A ``pallas`` request off the TPU raises rather
+    than falling back to the interpreter, so a run meant for the chip
+    that lands on the CPU stops instead of carrying on; ``interpret`` is
+    the CPU route for the same kernels."""
     if backend == "interpret":
         return True
     if jax.default_backend() != "tpu":
-        warnings.warn(
+        raise RuntimeError(
             f"kernel backend 'pallas' requested on "
-            f"{jax.default_backend()!r}: running in Pallas INTERPRETER "
-            "mode (orders of magnitude slower; not compiled-kernel "
-            "performance)", RuntimeWarning, stacklevel=3)
-        return True
+            f"{jax.default_backend()!r}: compiled Pallas kernels need a "
+            "TPU. Use backend 'interpret' (REPRO_KERNEL_BACKEND=interpret"
+            " or DFAConfig.kernel_backend='interpret') to run them in the "
+            "Pallas interpreter on this device")
     return False
+
+
+def tpu_kernels(hlo_text: str) -> List[str]:
+    """Names of the Pallas TPU kernels (``tpu_custom_call`` instructions)
+    in a compiled program's HLO text — e.g. ``ingest_update_block``,
+    ``ring_scatter``, ``gather_enrich_hbm`` (each ``pallas_call`` here
+    passes its ``name``; XLA appends a ``.N`` suffix, dropped here)."""
+    names = re.findall(
+        r'%([\w-]+?)(?:\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"',
+        hlo_text)
+    return sorted(set(names))
 
 
 def lookup(family: str, backend: Optional[str] = None,

@@ -1,27 +1,28 @@
-"""gather_enrich — fused history gather + feature derivation (Pallas).
+"""gather_enrich — history gather + feature derivation (Pallas).
 
-The unfused enrichment path gathers each routed report's (H, 16)-word ring
-history out of collector memory into an (R, H, 16) intermediate, then runs
-derived_features over it: one full round trip of 640 B/flow through HBM
-before the compute even starts. Both kernels here fuse the two stages so
-the (R, H, 16) array never exists in HBM — the TPU shape of the paper's
-"build derived features on CUDA cores right next to the GDR-placed
-telemetry" argument (§III-C). Two memory strategies:
+Enrichment reads each routed report's (H, 16)-word ring history out of
+collector memory and derives its feature vector. Both kernels here share
+derived_features' lane-dense math (``derive_rows``: reports on lanes,
+history entries on sublanes); they differ in where the gather happens.
 
 ``gather_enrich_pallas`` (full-block)
-    Collector memory is presented as one un-tiled VMEM block and rows are
-    copied scratch-to-scratch inside the kernel. Fastest when the shard
-    ring region fits VMEM (reduced configs); impossible at Tofino scale —
-    2^17 flows x 10 x 64 B is ~84 MB against ~16 MB of VMEM.
+    Collector memory is one un-tiled VMEM block, laid out as word-major
+    rows (F, W*H) padded to whole 128-lane tiles; flow ids are
+    scalar-prefetched into SMEM and rows are copied into a (T, ·) scratch
+    inside the kernel, then transposed so reports ride the lanes. Only
+    possible while the shard ring region fits VMEM (reduced configs);
+    impossible at Tofino scale — 2^17 flows x 10 x 64 B is ~84 MB against
+    ~16 MB of VMEM.
 
-``gather_enrich_hbm_pallas`` (HBM-resident, tiled)
-    Collector memory stays in HBM (``pltpu.ANY``); the routed flow ids are
-    scalar-prefetched into SMEM and a per-report-tile double-buffered DMA
-    loop (``pltpu.make_async_copy`` into two scratch slots) pulls each
-    flow's (H, 16) ring rows into VMEM while the previous tile's
-    derive_block computes. VMEM footprint is O(report_tile * H * 16)
-    regardless of F, which is what lets one shard own the paper's full
-    2^17-flow table.
+``gather_enrich_hbm_pallas`` (HBM-resident)
+    Collector memory stays in HBM and XLA gathers only the R routed
+    (H, 16) rows — R x 640 B, a few MB at the paper's R = 4096 — which the
+    derived_features kernel then streams through VMEM per report tile.
+    VMEM = O(report_tile * H * 16) regardless of F, which is what lets
+    one shard own the paper's full 2^17-flow table. The gather is not a
+    DMA inside the kernel: Mosaic lays the (F, H, 16) ring out with its
+    16-word minor dim padded to 128 lanes and cannot DMA a 16-word slice
+    of that tile.
 
 Variant selection (VMEM-budget heuristic + overrides) lives in
 repro.kernels.dispatch; both kernels compute bit-identical features.
@@ -36,9 +37,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import wire as WIRE
-from repro.kernels.derived_features.kernel import derive_block
+from repro.kernels.derived_features.kernel import (
+    derive_pallas, derive_rows, write_rows)
 
 WORDS = 16
+LANES = 128
+
+
+def _lane_pad(a: jax.Array) -> jax.Array:
+    """Pad the minor dim to a whole number of 128-lane tiles."""
+    pad = (-a.shape[-1]) % LANES
+    return jnp.pad(a, ((0, 0), (0, pad))) if pad else a
 
 
 # ---------------------------------------------------------------------------
@@ -46,18 +55,22 @@ WORDS = 16
 # ---------------------------------------------------------------------------
 
 def _full_kernel(flows_ref, mem_ref, valid_ref, out_ref, ent_scratch,
-                 val_scratch, *, derived_dim: int, wire: WIRE.WireFormat):
-    T = flows_ref.shape[0]
+                 val_scratch, *, history: int, report_tile: int,
+                 derived_dim: int, wire: WIRE.WireFormat):
+    base = pl.program_id(0) * report_tile
 
     def gather(r, _):
-        f = flows_ref[r]
-        ent_scratch[pl.ds(r, 1)] = mem_ref[pl.ds(f, 1)]
-        val_scratch[pl.ds(r, 1)] = valid_ref[pl.ds(f, 1)]
+        f = flows_ref[base + r]
+        ent_scratch[pl.ds(r, 1), :] = mem_ref[pl.ds(f, 1), :]
+        val_scratch[pl.ds(r, 1), :] = valid_ref[pl.ds(f, 1), :]
         return 0
 
-    jax.lax.fori_loop(0, T, gather, 0)
-    out_ref[...] = derive_block(ent_scratch[...], val_scratch[...] > 0,
-                                derived_dim, wire=wire)
+    jax.lax.fori_loop(0, report_tile, gather, 0)
+    H = history
+    ent = ent_scratch[...].T                 # (lanes(W*H), T) word-major
+    val = val_scratch[...].T[:H] > 0         # (H, T)
+    write_rows(out_ref, derive_rows(lambda w: ent[w * H:(w + 1) * H], val,
+                                    derived_dim, wire))
 
 
 @functools.partial(jax.jit,
@@ -74,79 +87,41 @@ def gather_enrich_pallas(memory: jax.Array, entry_valid: jax.Array,
     R = local_flow.shape[0]
     assert R % report_tile == 0 and W == WORDS, (R, report_tile, W)
     flows = jnp.clip(local_flow.astype(jnp.int32), 0, F - 1)
-
-    return pl.pallas_call(
-        functools.partial(_full_kernel, derived_dim=derived_dim,
-                          wire=wire),
+    rows = _lane_pad(memory.transpose(0, 2, 1).reshape(F, W * H))
+    valid = _lane_pad(entry_valid.astype(jnp.int32))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,            # flows -> SMEM, whole array
         grid=(R // report_tile,),
         in_specs=[
-            pl.BlockSpec((report_tile,), lambda r: (r,)),
-            pl.BlockSpec((F, H, WORDS), lambda r: (0, 0, 0)),
-            pl.BlockSpec((F, H), lambda r: (0, 0)),
+            pl.BlockSpec(rows.shape, lambda r, flows: (0, 0)),
+            pl.BlockSpec(valid.shape, lambda r, flows: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((report_tile, derived_dim), lambda r: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, derived_dim), jnp.float32),
+        out_specs=pl.BlockSpec((derived_dim, report_tile),
+                               lambda r, flows: (0, r)),
         scratch_shapes=[
-            pltpu.VMEM((report_tile, H, WORDS), jnp.uint32),
-            pltpu.VMEM((report_tile, H), jnp.int32),
+            pltpu.VMEM((report_tile, rows.shape[1]), jnp.uint32),
+            pltpu.VMEM((report_tile, valid.shape[1]), jnp.int32),
         ],
+    )
+    # the pinned ring block (double-buffered by the pipeline) is what
+    # this variant is for; give it room beyond the default scoped VMEM
+    ring_bytes = 4 * F * (rows.shape[1] + valid.shape[1])
+    out = pl.pallas_call(
+        functools.partial(_full_kernel, history=H, report_tile=report_tile,
+                          derived_dim=derived_dim, wire=wire),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((derived_dim, R), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=2 * ring_bytes + (32 << 20)),
         interpret=interpret,
-    )(flows, memory, entry_valid.astype(jnp.int32))
+        name="gather_enrich_full",
+    )(flows, rows, valid)
+    return out.T
 
 
 # ---------------------------------------------------------------------------
-# HBM-resident variant: ring region stays in HBM, per-tile DMA gather
+# HBM-resident variant: ring region stays in HBM, XLA gathers the R rows
 # ---------------------------------------------------------------------------
-
-N_SLOTS = 2          # double buffering: fetch tile i+1 while tile i computes
-SEM_ENT, SEM_VAL = 0, 1
-
-
-def _hbm_kernel(flows_ref, mem_ref, valid_ref, out_ref, ent_scratch,
-                val_scratch, sems, *, derived_dim: int, report_tile: int,
-                n_tiles: int, wire: WIRE.WireFormat):
-    """Grid step i: wait for tile i's rows (prefetched by step i-1, or by
-    the prologue for i == 0), kick off tile i+1's DMAs into the other
-    scratch slot, then derive tile i in place."""
-    i = pl.program_id(0)
-
-    def _row_copies(tile, slot, r):
-        f = flows_ref[tile * report_tile + r]
-        ent = pltpu.make_async_copy(mem_ref.at[f], ent_scratch.at[slot, r],
-                                    sems.at[slot, SEM_ENT])
-        val = pltpu.make_async_copy(valid_ref.at[f], val_scratch.at[slot, r],
-                                    sems.at[slot, SEM_VAL])
-        return ent, val
-
-    def start_tile(tile, slot):
-        def row(r, _):
-            ent, val = _row_copies(tile, slot, r)
-            ent.start()
-            val.start()
-            return 0
-        jax.lax.fori_loop(0, report_tile, row, 0)
-
-    def wait_tile(tile, slot):
-        def row(r, _):
-            ent, val = _row_copies(tile, slot, r)
-            ent.wait()
-            val.wait()
-            return 0
-        jax.lax.fori_loop(0, report_tile, row, 0)
-
-    @pl.when(i == 0)
-    def _prologue():
-        start_tile(0, 0)
-
-    @pl.when(i + 1 < n_tiles)
-    def _prefetch_next():
-        start_tile(i + 1, (i + 1) % N_SLOTS)
-
-    slot = i % N_SLOTS
-    wait_tile(i, slot)
-    out_ref[...] = derive_block(ent_scratch[slot], val_scratch[slot] > 0,
-                                derived_dim, wire=wire)
-
 
 @functools.partial(jax.jit,
                    static_argnames=("derived_dim", "report_tile",
@@ -157,34 +132,13 @@ def gather_enrich_hbm_pallas(memory: jax.Array, entry_valid: jax.Array,
                              interpret: bool = True,
                              wire: WIRE.WireFormat = WIRE.V1) -> jax.Array:
     """Same contract as gather_enrich_pallas, but ``memory``/``entry_valid``
-    never leave HBM as whole blocks: VMEM holds only two
-    (report_tile, H, 16) scratch slots, so F is unbounded by VMEM."""
+    never enter VMEM as whole blocks: only the R routed rows are read, so
+    F is unbounded by VMEM."""
     F, H, W = memory.shape
     R = local_flow.shape[0]
     assert R % report_tile == 0 and W == WORDS, (R, report_tile, W)
-    n_tiles = R // report_tile
     flows = jnp.clip(local_flow.astype(jnp.int32), 0, F - 1)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,            # flows -> SMEM, whole array
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),     # ring region (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),     # validity (HBM)
-        ],
-        out_specs=pl.BlockSpec((report_tile, derived_dim),
-                               lambda i, flows: (i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((N_SLOTS, report_tile, H, WORDS), jnp.uint32),
-            pltpu.VMEM((N_SLOTS, report_tile, H), jnp.int32),
-            pltpu.SemaphoreType.DMA((N_SLOTS, 2)),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_hbm_kernel, derived_dim=derived_dim,
-                          report_tile=report_tile, n_tiles=n_tiles,
-                          wire=wire),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, derived_dim), jnp.float32),
-        interpret=interpret,
-    )(flows, memory, entry_valid.astype(jnp.int32))
+    return derive_pallas(memory[flows], entry_valid[flows],
+                         derived_dim=derived_dim, flow_tile=report_tile,
+                         interpret=interpret, wire=wire,
+                         name="gather_enrich_hbm")

@@ -1,11 +1,12 @@
-"""Registry client for the fused gather_enrich op (pipeline stage 6).
+"""Registry client for the gather_enrich op (pipeline stage 6).
 
 Besides backend resolution (ref / pallas / interpret) this wrapper owns
 two pieces of shape policy the kernels don't:
 
 * memory-strategy variant selection — ``dispatch.resolve_gather_variant``
-  picks the full-block kernel while the shard ring region fits the VMEM
-  budget and the HBM-resident tiled kernel beyond (2^17 flows/shard), with
+  picks the ``full`` kernel (ring pinned in VMEM) while the shard ring
+  region fits the VMEM budget and the ``hbm`` path (XLA gathers the R
+  routed rows, the derive kernel streams them) beyond, with
   ``DFAConfig.gather_variant`` / ``REPRO_GATHER_VARIANT`` overrides;
 * report padding — R is padded up to a multiple of the report tile
   (clamped flow id 0 for pad rows, output rows sliced off) so callers can

@@ -31,11 +31,11 @@ Two event-stream memory strategies (mirroring gather_enrich):
     Pallas pipeline. Right while the stream fits the VMEM budget.
 
 ``ingest_update_hbm_pallas`` (HBM-resident)
-    The stream stays in HBM (``pltpu.ANY``); run-boundary metadata (the
+    The stream stays in HBM (``pl.ANY``); run-boundary metadata (the
     count of non-sentinel rows per tile) is scalar-prefetched into SMEM
     and a double-buffered ``pltpu.make_async_copy`` loop pulls each
-    event tile into 2-slot VMEM scratch while the previous tile's
-    reduction computes. VMEM = O(event_tile) regardless of E, so
+    group of event tiles into 2-slot VMEM scratch while the previous
+    group's reduction computes. VMEM = O(event_tile) regardless of E, so
     events_per_shard can grow to 2^20; all-pad tiles skip the matmuls.
 
 Variant selection (VMEM-budget heuristic + overrides) lives in
@@ -156,39 +156,110 @@ def apply_updates(regs: jax.Array, last_ts: jax.Array, keys: jax.Array,
 
 
 def delta_cols(iat: jax.Array, ps: jax.Array, bits: int, log_lut,
-               exp_lut):
+               exp_lut, take=LS.take_gather):
     """The seven Table-I delta columns (iat already zeroed for firsts).
     The log*/exp* LUTs arrive as arrays so kernel bodies can feed the
     refs they received as inputs (a captured jnp constant is illegal
-    inside pallas_call)."""
+    inside pallas_call); ``take`` reads them (logstar.take_onehot there)."""
     def pw(x, n):
-        return LS.approx_pow_with_luts(x, n, bits, log_lut, exp_lut)
+        return LS.approx_pow_with_luts(x, n, bits, log_lut, exp_lut, take)
 
     return (jnp.ones_like(ps), iat, pw(iat, 2), pw(iat, 3),
             ps, pw(ps, 2), pw(ps, 3))
 
 
+def _u32_to_f32_exact(x: jax.Array) -> jax.Array:
+    """u32 values below 2^31 -> f32 through int32 (Mosaic has no
+    unsigned -> float cast)."""
+    return x.astype(jnp.int32).astype(jnp.float32)
+
+
+def _f32_to_u32_exact(x: jax.Array) -> jax.Array:
+    """Integral f32 values below 2^31 -> u32 through int32."""
+    return x.astype(jnp.int32).astype(jnp.uint32)
+
+
 def _tile_sums(slot, ts, ps, base, first, log_lut, exp_lut, *,
                bits: int):
-    """(tile,) sorted inputs -> (tile, 8) u32 run-prefix segment sums.
+    """(1, tile) sorted inputs -> (8, tile) u32 run-prefix segment sums.
 
-    Row r holds the sum of its run's deltas from the run's first row
+    Column r holds the sum of its run's deltas from the run's first row
     inside this tile through r; run tails / tile cuts are therefore
-    exact per-(tile-)segment sums. u16-half matmul keeps u32 exactness
-    (tile <= 256 -> each half partial sum < 2^24 fits f32)."""
-    tile = slot.shape[0]
+    exact per-(tile-)segment sums. The work is laid out lane-major (one
+    (1, tile) row per quantity), the layout Mosaic lowers without
+    relayouts; the LUTs arrive as (n, 1) f32 columns for
+    logstar.take_onehot. u16-half matmul keeps u32 exactness (tile <= 256
+    -> each half partial sum < 2^24 fits f32; HIGHEST precision keeps
+    the MXU from rounding operands to bf16)."""
+    tile = slot.shape[-1]
     iat = jnp.where(first != 0, jnp.uint32(0), ts - base)
-    d = delta_cols(iat, ps, bits, log_lut, exp_lut)
-    D = jnp.stack(d + (jnp.zeros_like(ps),), axis=-1)   # (tile, 8) VMEM
-    lo = (D & jnp.uint32(0xFFFF)).astype(jnp.float32)
-    hi = (D >> 16).astype(jnp.float32)
-    r = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
-    c = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
-    m = ((slot[None, :] == slot[:, None]) & (c <= r)).astype(jnp.float32)
-    acc_lo = jnp.dot(m, lo, preferred_element_type=jnp.float32)
-    acc_hi = jnp.dot(m, hi, preferred_element_type=jnp.float32)
-    return (acc_lo.astype(jnp.uint32)
-            + (acc_hi.astype(jnp.uint32) << 16))
+    d = delta_cols(iat, ps, bits, log_lut, exp_lut, take=LS.take_onehot)
+    D = jnp.concatenate(d + (jnp.zeros_like(ps),), axis=0)  # (8, tile)
+    lo = _u32_to_f32_exact(D & jnp.uint32(0xFFFF))
+    hi = _u32_to_f32_exact(D >> 16)
+    # m[a, b] = (slot[a] == slot[b]) & (a <= b): row a feeds column b
+    s_b = jnp.broadcast_to(slot, (tile, tile))       # [a, b] = slot[b]
+    a = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+    b = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
+    m = ((s_b == s_b.T) & (a <= b)).astype(jnp.float32)
+    hp = jax.lax.Precision.HIGHEST
+    acc_lo = jnp.dot(lo, m, precision=hp,
+                     preferred_element_type=jnp.float32)
+    acc_hi = jnp.dot(hi, m, precision=hp,
+                     preferred_element_type=jnp.float32)
+    return _f32_to_u32_exact(acc_lo) + (_f32_to_u32_exact(acc_hi) << 16)
+
+
+# Both variants present the sorted stream to Mosaic as 2D (n_tiles, et)
+# arrays, one event tile per row: 1D operands carry XLA's T(1024) memory
+# tiling, which neither a 256-event block nor a 256-event DMA slice can
+# match. A grid step covers ``group`` rows (GROUP, the 32-bit sublane
+# count, or all of them when there are fewer), and the stream is padded
+# with sentinel rows to a whole number of groups.
+
+GROUP = 8
+N_STREAMS = 5        # slot / ts / ps / base_ts / first
+
+
+def _group_rows(n_tiles: int) -> int:
+    """Tiles per grid step: a full sublane group, or the whole stream."""
+    return n_tiles if n_tiles <= GROUP else GROUP
+
+
+def _stream_rows(stream, et: int):
+    """(Ep,) stream words -> (n_tiles, et) rows (XLA-side reshape)."""
+    return [a.reshape(-1, et) for a in stream]
+
+
+def _reduce_group(rows, luts, out_ref, *, group: int, bits: int,
+                  live=None):
+    """Reduce the ``group`` tiles of one grid step: tile k reads row k of
+    each of the five stream refs and writes out_ref[k] = (8, et) sums.
+    ``live(k)`` (optional) says whether tile k holds any real event; a
+    dead tile writes zeros and skips the matmuls."""
+    log_lut, exp_lut = (r[...] for r in luts)
+
+    def tile(k, _):
+        def reduce():
+            out_ref[k] = _tile_sums(*(r[pl.ds(k, 1), :] for r in rows),
+                                    log_lut, exp_lut, bits=bits)
+        if live is None:
+            reduce()
+        else:
+            pl.when(live(k))(reduce)
+
+            @pl.when(jnp.logical_not(live(k)))
+            def _dead():
+                out_ref[k] = jnp.zeros(out_ref.shape[1:], jnp.uint32)
+        return 0
+
+    jax.lax.fori_loop(0, group, tile, 0)
+
+
+def _sums_out(sums: jax.Array) -> jax.Array:
+    """(n_tiles, 8, et) kernel output -> (Ep, 8) per-event rows."""
+    n, r, et = sums.shape
+    return sums.transpose(0, 2, 1).reshape(n * et, r)
 
 
 # ---------------------------------------------------------------------------
@@ -196,10 +267,11 @@ def _tile_sums(slot, ts, ps, base, first, log_lut, exp_lut, *,
 # ---------------------------------------------------------------------------
 
 def _block_kernel(slot_ref, ts_ref, ps_ref, base_ref, first_ref,
-                  loglut_ref, explut_ref, out_ref, *, bits: int):
-    out_ref[...] = _tile_sums(slot_ref[...], ts_ref[...], ps_ref[...],
-                              base_ref[...], first_ref[...],
-                              loglut_ref[...], explut_ref[...], bits=bits)
+                  loglut_ref, explut_ref, out_ref, *, group: int,
+                  bits: int):
+    _reduce_group((slot_ref, ts_ref, ps_ref, base_ref, first_ref),
+                  (loglut_ref, explut_ref), out_ref, group=group,
+                  bits=bits)
 
 
 @functools.partial(jax.jit,
@@ -207,80 +279,75 @@ def _block_kernel(slot_ref, ts_ref, ps_ref, base_ref, first_ref,
 def segment_sums_pallas(s_slot, s_ts, s_ps, base_ts, first_i32, *,
                         bits: int, event_tile: int,
                         interpret: bool = True) -> jax.Array:
-    """(Ep,) sorted stream -> (Ep, 8) per-tile-segment sums (block)."""
-    Ep = s_slot.shape[0]
-    assert Ep % event_tile == 0, (Ep, event_tile)
+    """(Ep,) sorted stream -> (Ep, 8) per-tile-segment sums (block).
+    Ep is a whole number of tile groups (see :func:`_group_rows`)."""
     et = event_tile
-    log_lut, exp_lut = (jnp.asarray(t) for t in LS._luts(bits))
+    rows = _stream_rows((s_slot, s_ts, s_ps, base_ts, first_i32), et)
+    n_tiles = rows[0].shape[0]
+    group = _group_rows(n_tiles)
+    assert n_tiles % group == 0, (n_tiles, group)
+    log_lut, exp_lut = (LS.lut_column(t) for t in LS._luts(bits))
     n_lut = 1 << bits
-    return pl.pallas_call(
-        functools.partial(_block_kernel, bits=bits),
-        grid=(Ep // et,),
-        in_specs=[pl.BlockSpec((et,), lambda i: (i,))] * 5
-        + [pl.BlockSpec((n_lut,), lambda i: (0,))] * 2,
-        out_specs=pl.BlockSpec((et, REG_PAD), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Ep, REG_PAD), jnp.uint32),
+    sums = pl.pallas_call(
+        functools.partial(_block_kernel, group=group, bits=bits),
+        grid=(n_tiles // group,),
+        in_specs=[pl.BlockSpec((group, et), lambda g: (g, 0))] * N_STREAMS
+        + [pl.BlockSpec((n_lut, 1), lambda g: (0, 0))] * 2,
+        out_specs=pl.BlockSpec((group, REG_PAD, et), lambda g: (g, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, REG_PAD, et), jnp.uint32),
         interpret=interpret,
-    )(s_slot, s_ts, s_ps, base_ts, first_i32, log_lut, exp_lut)
+        name="ingest_update_block",
+    )(*rows, log_lut, exp_lut)
+    return _sums_out(sums)
 
 
 # ---------------------------------------------------------------------------
-# HBM-resident variant: stream stays in HBM, double-buffered tile DMA
+# HBM-resident variant: stream stays in HBM, double-buffered group DMA
 # ---------------------------------------------------------------------------
 
-N_SLOTS = 2          # double buffering: fetch tile i+1 while tile i computes
-N_STREAMS = 5        # slot / ts / ps / base_ts / first
+N_SLOTS = 2          # double buffering: fetch group g+1 while g computes
 
 
 def _hbm_kernel(meta_ref, slot_hbm, ts_hbm, ps_hbm, base_hbm, first_hbm,
                 loglut_ref, explut_ref, out_ref, slot_s, ts_s, ps_s,
-                base_s, first_s, sems, *, bits: int, event_tile: int,
-                n_tiles: int):
-    """Grid step i: wait for tile i's five stream slices (prefetched by
-    step i-1, or by the prologue for i == 0), kick off tile i+1's DMAs
-    into the other scratch slot, then reduce tile i. ``meta_ref`` is the
+                base_s, first_s, sems, *, group: int, n_groups: int,
+                bits: int):
+    """Grid step g: wait for group g's five stream slices (prefetched by
+    step g-1, or by the prologue for g == 0), kick off group g+1's DMAs
+    into the other scratch slot, then reduce group g. ``meta_ref`` is the
     scalar-prefetched run-boundary metadata: the count of non-sentinel
     rows per tile, so all-pad tiles skip the matmul work entirely."""
-    i = pl.program_id(0)
-    et = event_tile
+    g = pl.program_id(0)
+    streams = [(slot_hbm, slot_s), (ts_hbm, ts_s), (ps_hbm, ps_s),
+               (base_hbm, base_s), (first_hbm, first_s)]
 
-    def _copies(tile, buf):
-        sl = pl.ds(tile * et, et)
+    def _copies(grp, buf):
+        sl = pl.ds(grp * group, group)
         return [pltpu.make_async_copy(hbm.at[sl], scr.at[buf],
                                       sems.at[buf, j])
-                for j, (hbm, scr) in enumerate(
-                    [(slot_hbm, slot_s), (ts_hbm, ts_s), (ps_hbm, ps_s),
-                     (base_hbm, base_s), (first_hbm, first_s)])]
+                for j, (hbm, scr) in enumerate(streams)]
 
-    def start_tile(tile, buf):
-        for dma in _copies(tile, buf):
+    def start_group(grp, buf):
+        for dma in _copies(grp, buf):
             dma.start()
 
-    def wait_tile(tile, buf):
-        for dma in _copies(tile, buf):
+    def wait_group(grp, buf):
+        for dma in _copies(grp, buf):
             dma.wait()
 
-    @pl.when(i == 0)
+    @pl.when(g == 0)
     def _prologue():
-        start_tile(0, 0)
+        start_group(0, 0)
 
-    @pl.when(i + 1 < n_tiles)
+    @pl.when(g + 1 < n_groups)
     def _prefetch_next():
-        start_tile(i + 1, (i + 1) % N_SLOTS)
+        start_group(g + 1, (g + 1) % N_SLOTS)
 
-    buf = i % N_SLOTS
-    wait_tile(i, buf)
-
-    @pl.when(meta_ref[i] > 0)
-    def _reduce():
-        out_ref[...] = _tile_sums(slot_s[buf], ts_s[buf], ps_s[buf],
-                                  base_s[buf], first_s[buf],
-                                  loglut_ref[...], explut_ref[...],
-                                  bits=bits)
-
-    @pl.when(meta_ref[i] == 0)
-    def _pad_tile():
-        out_ref[...] = jnp.zeros((et, REG_PAD), jnp.uint32)
+    buf = g % N_SLOTS
+    wait_group(g, buf)
+    _reduce_group([scr.at[buf] for _, scr in streams],
+                  (loglut_ref, explut_ref), out_ref, group=group,
+                  bits=bits, live=lambda k: meta_ref[g * group + k] > 0)
 
 
 @functools.partial(jax.jit,
@@ -290,38 +357,42 @@ def segment_sums_hbm_pallas(tile_nreal, s_slot, s_ts, s_ps, base_ts,
                             interpret: bool = True) -> jax.Array:
     """Same contract as :func:`segment_sums_pallas`, but the five stream
     arrays never leave HBM as whole blocks: VMEM holds two
-    (event_tile,)-slot scratch sets, so E is unbounded by VMEM.
+    (group, event_tile)-slot scratch sets, so E is unbounded by VMEM.
     ``tile_nreal`` (n_tiles,) i32 is the scalar-prefetched count of
     non-sentinel rows per tile."""
-    Ep = s_slot.shape[0]
-    assert Ep % event_tile == 0, (Ep, event_tile)
     et = event_tile
-    n_tiles = Ep // et
-    log_lut, exp_lut = (jnp.asarray(t) for t in LS._luts(bits))
+    rows = _stream_rows((s_slot, s_ts, s_ps, base_ts, first_i32), et)
+    n_tiles = rows[0].shape[0]
+    group = _group_rows(n_tiles)
+    assert n_tiles % group == 0, (n_tiles, group)
+    n_groups = n_tiles // group
+    log_lut, exp_lut = (LS.lut_column(t) for t in LS._luts(bits))
     n_lut = 1 << bits
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,            # tile_nreal -> SMEM, whole array
-        grid=(n_tiles,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * N_STREAMS
-        + [pl.BlockSpec((n_lut,), lambda i, meta: (0,))] * 2,
-        out_specs=pl.BlockSpec((et, REG_PAD), lambda i, meta: (i, 0)),
+        grid=(n_groups,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * N_STREAMS
+        + [pl.BlockSpec((n_lut, 1), lambda g, meta: (0, 0))] * 2,
+        out_specs=pl.BlockSpec((group, REG_PAD, et),
+                               lambda g, meta: (g, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((N_SLOTS, et), jnp.int32),     # slot
-            pltpu.VMEM((N_SLOTS, et), jnp.uint32),    # ts
-            pltpu.VMEM((N_SLOTS, et), jnp.uint32),    # ps
-            pltpu.VMEM((N_SLOTS, et), jnp.uint32),    # base_ts
-            pltpu.VMEM((N_SLOTS, et), jnp.int32),     # first
+            pltpu.VMEM((N_SLOTS, group, et), jnp.int32),     # slot
+            pltpu.VMEM((N_SLOTS, group, et), jnp.uint32),    # ts
+            pltpu.VMEM((N_SLOTS, group, et), jnp.uint32),    # ps
+            pltpu.VMEM((N_SLOTS, group, et), jnp.uint32),    # base_ts
+            pltpu.VMEM((N_SLOTS, group, et), jnp.int32),     # first
             pltpu.SemaphoreType.DMA((N_SLOTS, N_STREAMS)),
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_hbm_kernel, bits=bits, event_tile=et,
-                          n_tiles=n_tiles),
+    sums = pl.pallas_call(
+        functools.partial(_hbm_kernel, group=group, n_groups=n_groups,
+                          bits=bits),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Ep, REG_PAD), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, REG_PAD, et), jnp.uint32),
         interpret=interpret,
-    )(tile_nreal, s_slot, s_ts, s_ps, base_ts, first_i32, log_lut,
-      exp_lut)
+        name="ingest_update_hbm",
+    )(tile_nreal, *rows, log_lut, exp_lut)
+    return _sums_out(sums)
 
 
 # ---------------------------------------------------------------------------
@@ -331,29 +402,38 @@ def segment_sums_hbm_pallas(tile_nreal, s_slot, s_ts, s_ps, base_ts,
 def _fused_pallas(regs, last_ts, keys, active, collisions, slots, ts, ps,
                   five_tuple, valid, *, logstar_bits, event_tile,
                   interpret, hbm):
+    F = regs.shape[0]
     st = stream_prep(last_ts, keys, active, slots, ts, ps, five_tuple,
                      valid, event_tile)
-    first_i32 = st.first.astype(jnp.int32)
+    et = st.tile
+    Ep = st.s_slot.shape[0]
+    # sentinel rows up to a whole number of tile groups (kernel input
+    # only: the sums of pad tiles are sliced off before the scatter)
+    n_tiles = Ep // et
+    extra = ((-n_tiles) % _group_rows(n_tiles)) * et
+
+    def grp_pad(a, c=0):
+        return jnp.pad(a, (0, extra), constant_values=c) if extra else a
+
+    stream = (grp_pad(st.s_slot, F), grp_pad(st.s_ts), grp_pad(st.s_ps),
+              grp_pad(st.base_ts), grp_pad(st.first.astype(jnp.int32)))
     if hbm:
-        et = st.tile
-        n_tiles = st.s_slot.shape[0] // et
-        n_real = jnp.sum(st.s_slot < regs.shape[0]).astype(jnp.int32)
+        n_real = jnp.sum(st.s_slot < F).astype(jnp.int32)
         tile_nreal = jnp.clip(
-            n_real - jnp.arange(n_tiles, dtype=jnp.int32) * et, 0, et)
+            n_real - jnp.arange(n_tiles + extra // et, dtype=jnp.int32)
+            * et, 0, et)
         sums = segment_sums_hbm_pallas(
-            tile_nreal, st.s_slot, st.s_ts, st.s_ps, st.base_ts,
-            first_i32, bits=logstar_bits, event_tile=et,
+            tile_nreal, *stream, bits=logstar_bits, event_tile=et,
             interpret=interpret)
     else:
         sums = segment_sums_pallas(
-            st.s_slot, st.s_ts, st.s_ps, st.base_ts, first_i32,
-            bits=logstar_bits, event_tile=st.tile, interpret=interpret)
+            *stream, bits=logstar_bits, event_tile=et, interpret=interpret)
     # a run's sum is cut at every tile boundary it crosses; the scatter
     # re-merges the partials (one contributing row per run per tile)
-    idx = jnp.arange(st.s_slot.shape[0], dtype=jnp.int32)
-    tile_cut = (idx % st.tile) == (st.tile - 1)
+    idx = jnp.arange(Ep, dtype=jnp.int32)
+    tile_cut = (idx % et) == (et - 1)
     return apply_updates(regs, last_ts, keys, active, collisions, st,
-                         sums, st.run_tail | tile_cut)
+                         sums[:Ep], st.run_tail | tile_cut)
 
 
 def ingest_update_pallas(regs, last_ts, keys, active, collisions, slots,

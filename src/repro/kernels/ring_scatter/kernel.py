@@ -71,5 +71,6 @@ def ring_scatter_pallas(memory: jax.Array, payloads: jax.Array,
         out_shape=jax.ShapeDtypeStruct((F, H, WORDS), jnp.uint32),
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="ring_scatter",
     )(coords, payloads, memory)
     return out

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size as _axis_size, shard_map as _shard_map
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models.param import ParamDesc
@@ -272,7 +271,7 @@ def full_attention(q, k, v, *, scale: Optional[float] = None) -> jax.Array:
 def _linear_axis_index(axes: Sequence[str]) -> jax.Array:
     idx = jnp.zeros((), jnp.int32)
     for ax in axes:
-        idx = idx * _axis_size(ax) + jax.lax.axis_index(ax)
+        idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return idx
 
 
@@ -339,14 +338,14 @@ def flash_decode(q, k_cache, v_cache, k_new, v_new, pos, *, mesh: Mesh,
 
     ba = batch_axes if batch_axes else None
     sa = seq_axes if seq_axes else None
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(ba, None, None), P(ba, sa, None, None),
                   P(ba, sa, None, None), P(ba, None, None),
                   P(ba, None, None), P(ba)),
         out_specs=(P(ba, None, None), P(ba, sa, None, None),
                    P(ba, sa, None, None)),
-        check=False)
+        check_vma=False)
     return fn(q, k_cache, v_cache, k_new, v_new, pos)
 
 
@@ -519,13 +518,13 @@ def mla_decode(params, x, cfg: ModelConfig, ckv_cache, krope_cache, pos, *,
 
     ba = batch_axes if batch_axes else None
     sa = seq_axes if seq_axes else None
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(ba, None, None), P(ba, None, None), P(ba, sa, None),
                   P(ba, sa, None), P(ba, None, None), P(ba, None, None),
                   P(ba)),
         out_specs=(P(ba, None, None), P(ba, sa, None), P(ba, sa, None)),
-        check=False)
+        check_vma=False)
     o_lat, ckv_cache, krope_cache = fn(
         q_abs, q_rope[:, 0], ckv_cache, krope_cache, c_new, kr_new, pos)
     # absorb v_up on the way out: o[b,h,p] = sum_r o_lat[b,h,r] Wv[r,h,p]

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size as _axis_size, shard_map as _shard_map
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models.param import ParamDesc
@@ -168,7 +167,7 @@ def moe_ffn(params, x, cfg: ModelConfig, mesh: Mesh,
         w, idx = route(p, xf, cfg)
         my_rank = jnp.zeros((), jnp.int32)
         for a in ep_axes:
-            my_rank = my_rank * _axis_size(a) + jax.lax.axis_index(a)
+            my_rank = my_rank * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         my_first = my_rank * E_loc
         out = _expert_gather_compute(
             xf, w.reshape(-1), idx.reshape(-1).astype(jnp.int32),
@@ -177,12 +176,12 @@ def moe_ffn(params, x, cfg: ModelConfig, mesh: Mesh,
         return out.reshape(xb.shape).astype(xb.dtype)
 
     espec = ep_axes[0] if len(ep_axes) == 1 else tuple(ep_axes)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(ba, None, None), P(None, None), P(None),
                   P(espec, None, None), P(espec, None, None),
                   P(espec, None, None)),
-        out_specs=P(ba, None, None), check=False)
+        out_specs=P(ba, None, None), check_vma=False)
     y = fn(x, params["router"], bias, params["gate"], params["up"],
            params["down"])
     if m.num_shared_experts:

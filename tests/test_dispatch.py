@@ -125,8 +125,8 @@ def test_gather_variant_vmem_budget_heuristic(monkeypatch):
     monkeypatch.delenv(dispatch.GATHER_ENV_VAR, raising=False)
     reduced = get_dfa_config(reduced=True)
     paper = get_dfa_config()
-    # reduced ring (~170 KB) fits a 16 MB budget; paper ring (~84 MB)
-    # cannot -> the Tofino-scale config auto-selects the HBM-tiled path
+    # reduced ring (256 flows) fits a 16 MB budget; paper ring (2^17
+    # flows) cannot -> the Tofino-scale config auto-selects hbm
     assert dispatch.resolve_gather_variant(
         None, reduced, reduced.flows_per_shard, reduced.history, 64,
         reduced.derived_dim) == "full"
@@ -143,6 +143,25 @@ def test_gather_variant_vmem_budget_heuristic(monkeypatch):
         "hbm", 1 << 17, 10, 512, 96) == dispatch.gather_vmem_bytes(
         "hbm", 256, 10, 512, 96)
     assert dispatch.ring_vmem_bytes(1 << 17, 10) > 16 * 2**20
+
+
+def test_gather_vmem_model_counts_mosaic_padding(monkeypatch):
+    """The full kernel pins (F, 16*H) rows and (F, H) validity with each
+    minor dim padded to 128 lanes, double-buffered: 160 -> 256 and
+    10 -> 128 lanes at H = 10, so 2^12 flows is the last power of two
+    that fits 16 MB."""
+    monkeypatch.delenv(dispatch.GATHER_ENV_VAR, raising=False)
+    assert dispatch.ring_vmem_bytes(1 << 12, 10) == 2 * 4 * (1 << 12) * (
+        256 + 128)
+    cfg = get_dfa_config()
+    D = cfg.derived_dim
+    assert dispatch.resolve_gather_variant(None, cfg, 1 << 12, 10, 128,
+                                           D) == "full"
+    assert dispatch.resolve_gather_variant(None, cfg, 1 << 13, 10, 128,
+                                           D) == "hbm"
+    # a report tile narrower than a lane tile still occupies 128 lanes
+    assert dispatch.gather_vmem_bytes("hbm", 1, 10, 64, D) == \
+        dispatch.gather_vmem_bytes("hbm", 1, 10, 128, D)
 
 
 # -- per-family ref vs interpret equivalence ---------------------------------
@@ -178,6 +197,28 @@ def test_ring_scatter_ref_vs_interpret(rng):
     got = ring_scatter(J(mem), J(pays), J(flow), J(hist), J(mask),
                        backend="interpret", cfg=cfg)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+def test_pallas_off_tpu_raises_and_interpret_runs(rng):
+    """A 'pallas' request off the TPU refuses instead of falling back to
+    the interpreter (a run meant for the chip must not carry on on the
+    CPU); 'interpret' stays the CPU route and still runs."""
+    assert jax.default_backend() != "tpu"
+    assert dispatch.interpret_flag("interpret") is True
+    with pytest.raises(RuntimeError, match="'interpret'"):
+        dispatch.interpret_flag("pallas")
+    cfg = get_dfa_config(reduced=True)
+    F, H = cfg.flows_per_shard, cfg.history
+    mem = J(np.zeros((F, H, 16), np.uint32))
+    pays = J(rng.integers(0, 2**32, size=(8, 16),
+                          dtype=np.uint64).astype(np.uint32))
+    flow, hist = J(np.arange(8, dtype=np.int32)), J(np.zeros(8, np.int32))
+    mask = J(np.ones(8, bool))
+    with pytest.raises(RuntimeError, match="compiled Pallas kernels"):
+        ring_scatter(mem, pays, flow, hist, mask, backend="pallas", cfg=cfg)
+    got = ring_scatter(mem, pays, flow, hist, mask, backend="interpret",
+                       cfg=cfg)
+    np.testing.assert_array_equal(np.asarray(got)[:8, 0], np.asarray(pays))
 
 
 def test_derived_features_ref_vs_interpret(rng):
@@ -225,8 +266,13 @@ def test_gather_enrich_fused_matches_unfused_composition(rng):
     want = ENR.derive_ref(entries, evq, cfg)
     got = gather_enrich(st.memory, st.entry_valid, lf, cfg,
                         backend="interpret")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
+    # per-row feature-scale tolerance (test_gather_enrich_equiv's
+    # contract): the delta columns are differences of ~1e6 operands, so
+    # one ulp of the window mean is ~1e-4 of a small delta elementwise
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape
+    scale = np.maximum(1.0, np.abs(want).max(axis=-1, keepdims=True))
+    assert (np.abs(got - want) / scale).max() <= 1e-5
 
 
 def test_flash_attention_ref_vs_interpret(rng):

@@ -33,7 +33,8 @@ def run_sub(code: str):
 PRELUDE = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.compat import make_mesh, shard_map
+from repro.compat import make_mesh
+from jax import shard_map
 mesh = make_mesh((2,2,2), ("pod","data","model"))
 rng = np.random.default_rng(0)
 """
@@ -175,7 +176,7 @@ def f(g, e):
 fn = shard_map(f, mesh=mesh,
                in_specs=(P(("pod","data"), None), P(("pod","data"), None)),
                out_specs=(P(("pod","data"), None), P(("pod","data"), None)),
-               check=False)
+               check_vma=False)
 with mesh:
     got, _ = jax.jit(fn)(g, err)
 # exact mean over the 4 (pod,data) ranks, per model-replica

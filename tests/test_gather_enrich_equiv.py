@@ -4,11 +4,11 @@ Three implementations must agree on every input:
 
 * ref                — jnp oracle (explicit gather + derive_ref)
 * full-block kernel  — ring region pinned in VMEM (interpret mode)
-* HBM-tiled kernel   — ring stays in HBM, double-buffered per-tile DMA
-                       (interpret mode)
+* HBM-resident kernel — ring stays in HBM, only the routed rows are
+                       read (interpret mode)
 
 Comparison contract: the two Pallas kernels are BITWISE equal (same
-derive_block math on identically gathered rows), and each matches the ref
+derive_rows math on identically gathered rows), and each matches the ref
 oracle to <= 1e-5 relative to the row's feature scale. Elementwise rtol is
 the wrong yardstick here: the delta columns are newest-minus-window-mean
 differences of ~1e6-magnitude operands, so a single-ulp reduction-order
@@ -117,7 +117,7 @@ def test_mixed_validity_and_clamped_out_of_range_flows(rng):
 
 def test_paper_scale_f17_h8_hbm_interpret(rng):
     """Acceptance shape: F = 2^17 flows/shard, H = 8 — the ring region
-    (~71 MB) can't be a VMEM block; the HBM-tiled kernel must match the
+    (~71 MB) can't be a VMEM block; the HBM-resident path must match the
     oracle, and auto-selection must pick it."""
     from repro.kernels import dispatch
     cfg = dataclasses.replace(get_dfa_config(), history=8, flow_tile=128)
