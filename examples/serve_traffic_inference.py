@@ -68,7 +68,9 @@ def main():
     t0 = time.time()
     with mesh:
         loop = ServingLoop(system, build_source(system, events, nows))
+        t_loop = time.time()
         report = loop.run(periods)          # drains the queue on shutdown
+        loop_s = time.time() - t_loop
         out = report.last                    # StepOutputs, final period
         em = np.asarray(out.mask)
         verdicts = np.asarray(jnp.argmax(out.preds, axis=-1))
@@ -100,8 +102,9 @@ def main():
           f"p999 {lat['p999'] / 1000:.1f} ms; "
           f"{report.violations} budget violations "
           f"(CPU container — TPU is the SLO target)")
-    print(f"sustained {report.sustained_eps:.3e} events/s of "
-          f"{system.cfg.serve_offered_eps:.3e} offered")
+    print(f"processed {report.processed / loop_s:.3e} events/s of wall "
+          f"time ({system.cfg.serve_offered_eps:.3e} offered, first "
+          f"period's compile included)")
     v, c = np.unique(verdicts[em], return_counts=True)
     print(f"final period: {int(em.sum())} flows enriched, verdict "
           f"histogram {dict(zip(v.tolist(), c.tolist()))}")

@@ -85,55 +85,61 @@ def ingest(state: CollectorState, payloads: jax.Array, mask: jax.Array,
             return ring_scatter_collector(memory, entry_valid, pays, flow,
                                           hist, m, cfg=cfg)
 
-    p = PROTO.unpack_payload(payloads, wire=wf)
-    ok_csum = PROTO.payload_valid(payloads, wire=wf)
-    bad = jnp.sum(mask & ~ok_csum)  # corrupted/tampered payloads (§VI-B)
-    mask = mask & ok_csum
-    local = (p["flow_id"].astype(jnp.int32)
-             - jnp.asarray(shard_flow_base, jnp.int32))
-    in_range = (local >= 0) & (local < cfg.flows_per_shard)
-    mask = mask & in_range
-    # sequence continuity per reporter (last_seq stores seq+1; 0 = reporter
-    # never seen). The wrap mask and dup window scale with the schema's seq
-    # width — V1 keeps the paper's 8-bit space / 8-deep window, V2's u16
-    # space gets a 2048-deep one. Duplicates are REJECTED before placement
-    # (first arrival wins), so a replayed payload with a valid checksum but
-    # a stale (reporter, seq) identity can never overwrite ring state.
-    n_rep = wf.n_reporters
-    rep = p["reporter_id"].astype(jnp.int32)
-    seq = p["seq"].astype(jnp.uint32)
-    prev = state.last_seq[jnp.clip(rep, 0, n_rep - 1)]
-    prev_seq = (prev - 1) & jnp.uint32(wf.seq_mask)
-    dup_window = mask & (prev > 0) & (seq <= prev_seq) & (
-        prev_seq - seq < jnp.uint32(wf.seq_dup_window)
-    )                                 # small window => duplicate/replay
-    # within-batch duplicates: two rows carrying the same (reporter, seq)
-    # identity in one ingest. Sort valid rows by identity key (stable, so
-    # equal keys keep arrival order — first arrival wins), mark every
-    # non-first member of an equal-key run.
-    ident = rep.astype(jnp.uint32) * jnp.uint32(wf.seq_mask + 1) + seq
-    o1 = jnp.argsort(ident, stable=True)
-    order = o1[jnp.argsort((~mask)[o1], stable=True)]  # valid rows first
-    sk, sm = ident[order], mask[order]
-    run = jnp.concatenate([jnp.zeros((1,), bool),
-                           (sk[1:] == sk[:-1]) & sm[1:] & sm[:-1]])
-    dup_batch = jnp.zeros_like(mask).at[order].set(run)
-    dup = dup_window | dup_batch
-    mask_ok = mask & ~dup
-    memory, ev = scatter_fn(state.memory, state.entry_valid, payloads,
-                            jnp.clip(local, 0, cfg.flows_per_shard - 1),
-                            p["hist_idx"].astype(jnp.int32), mask_ok)
-    anomalies = state.seq_anomalies + jnp.sum(dup).astype(jnp.uint32)
-    new_seq = state.last_seq.at[jnp.where(mask_ok, rep, n_rep)].max(
-        seq + 1, mode="drop")
-    # seq-GAP loss detection (unwrapped regime): per reporter, the window
-    # advanced by (new - old) seqs this batch but only `fresh` of them
-    # landed — the difference is reports sent on the wire that never
-    # arrived (or arrived corrupted and were discarded above).
-    fresh = mask_ok & (seq + 1 >= prev)
-    cnt = jnp.zeros((n_rep + 1,), jnp.uint32).at[
-        jnp.where(fresh, rep, n_rep)].add(1, mode="drop")[:n_rep]
-    gap = jnp.sum(new_seq - state.last_seq) - jnp.sum(cnt)
+    # validate: checksum, flow range and the §VI-B sequence window, with
+    # the counters they feed; place: the ring write of what passed
+    with jax.named_scope("validate"):
+        p = PROTO.unpack_payload(payloads, wire=wf)
+        ok_csum = PROTO.payload_valid(payloads, wire=wf)
+        bad = jnp.sum(mask & ~ok_csum)  # corrupted/tampered payloads (§VI-B)
+        mask = mask & ok_csum
+        local = (p["flow_id"].astype(jnp.int32)
+                 - jnp.asarray(shard_flow_base, jnp.int32))
+        in_range = (local >= 0) & (local < cfg.flows_per_shard)
+        mask = mask & in_range
+        # sequence continuity per reporter (last_seq stores seq+1; 0 =
+        # reporter never seen). The wrap mask and dup window scale with
+        # the schema's seq width — V1 keeps the paper's 8-bit space /
+        # 8-deep window, V2's u16 space gets a 2048-deep one. Duplicates
+        # are REJECTED before placement (first arrival wins), so a
+        # replayed payload with a valid checksum but a stale (reporter,
+        # seq) identity can never overwrite ring state.
+        n_rep = wf.n_reporters
+        rep = p["reporter_id"].astype(jnp.int32)
+        seq = p["seq"].astype(jnp.uint32)
+        prev = state.last_seq[jnp.clip(rep, 0, n_rep - 1)]
+        prev_seq = (prev - 1) & jnp.uint32(wf.seq_mask)
+        dup_window = mask & (prev > 0) & (seq <= prev_seq) & (
+            prev_seq - seq < jnp.uint32(wf.seq_dup_window)
+        )                             # small window => duplicate/replay
+        # within-batch duplicates: two rows carrying the same (reporter,
+        # seq) identity in one ingest. Sort valid rows by identity key
+        # (stable, so equal keys keep arrival order — first arrival
+        # wins), mark every non-first member of an equal-key run.
+        ident = rep.astype(jnp.uint32) * jnp.uint32(wf.seq_mask + 1) + seq
+        o1 = jnp.argsort(ident, stable=True)
+        order = o1[jnp.argsort((~mask)[o1], stable=True)]  # valid first
+        sk, sm = ident[order], mask[order]
+        run = jnp.concatenate([jnp.zeros((1,), bool),
+                               (sk[1:] == sk[:-1]) & sm[1:] & sm[:-1]])
+        dup_batch = jnp.zeros_like(mask).at[order].set(run)
+        dup = dup_window | dup_batch
+        mask_ok = mask & ~dup
+        anomalies = state.seq_anomalies + jnp.sum(dup).astype(jnp.uint32)
+        new_seq = state.last_seq.at[jnp.where(mask_ok, rep, n_rep)].max(
+            seq + 1, mode="drop")
+        # seq-GAP loss detection (unwrapped regime): per reporter, the
+        # window advanced by (new - old) seqs this batch but only `fresh`
+        # of them landed — the difference is reports sent on the wire
+        # that never arrived (or arrived corrupted and were discarded
+        # above).
+        fresh = mask_ok & (seq + 1 >= prev)
+        cnt = jnp.zeros((n_rep + 1,), jnp.uint32).at[
+            jnp.where(fresh, rep, n_rep)].add(1, mode="drop")[:n_rep]
+        gap = jnp.sum(new_seq - state.last_seq) - jnp.sum(cnt)
+    with jax.named_scope("place"):
+        memory, ev = scatter_fn(state.memory, state.entry_valid, payloads,
+                                jnp.clip(local, 0, cfg.flows_per_shard - 1),
+                                p["hist_idx"].astype(jnp.int32), mask_ok)
     return state._replace(
         memory=memory, entry_valid=ev, last_seq=new_seq,
         bad_checksum=state.bad_checksum + bad.astype(jnp.uint32),

@@ -45,7 +45,16 @@ changing a single emitted feature.
 Per-period ``metrics`` are all deltas: ``collisions`` / ``bad_checksum`` /
 ``seq_anomalies`` report what THIS period added (the cumulative counters
 stay in the state), matching ``reports_sent`` / ``reports_recv`` /
-``bucket_drops`` which were always per-period.
+``bucket_drops`` which were always per-period. ``reports_due`` counts the
+flows due a report before the report capacity cuts them, so
+``reports_due - reports_sent`` is what the period deferred.
+
+Every stage runs under a ``jax.named_scope`` — ``reporter`` (``ingest``,
+``due``, ``reports``), ``route``, ``exchange`` (the (pod, shard) mesh),
+``translate``, ``faults`` (when armed), ``collector`` (``validate``,
+``place``) and ``enrich`` (``infer`` when a head is armed) — so each
+device op's ``op_name`` metadata, and with it a profiler trace, names
+the stage that owns it.
 
 Multi-pod (2D mesh) streaming: with ``cfg.flow_home == "hash"`` the same
 drivers run on a ``(pod, shard)`` mesh (``launch.mesh.make_dfa_mesh``).
@@ -363,9 +372,9 @@ class DFASystem:
                        out_shardings=self.state_shardings())()
 
     # -- the step (two half-steps) ----------------------------------------
-    _METRIC_KEYS = ("reports_sent", "reports_recv", "bucket_drops",
-                    "misroutes", "collisions", "bad_checksum",
-                    "seq_anomalies", "lost_reports")
+    _METRIC_KEYS = ("reports_sent", "reports_due", "reports_recv",
+                    "bucket_drops", "misroutes", "collisions",
+                    "bad_checksum", "seq_anomalies", "lost_reports")
 
     @property
     def fault_spec(self) -> Optional[FAULTS.FaultSpec]:
@@ -425,36 +434,46 @@ class DFASystem:
             # registry: ref = multipass oracle, pallas/interpret = fused
             # sort-once kernel; cfg.ingest_variant/event_tile select the
             # event-stream memory strategy)
-            rep_st = REP.ingest(rep_st, {"ts": ev_ts, "size": ev_sz,
-                                         "five_tuple": ev_tu,
-                                         "valid": ev_va}, cfg)
-            # 2. due flows -> DTA reports
-            slots, mask = REP.due_flows(rep_st, now_, cfg,
-                                        cfg.report_capacity)
-            rep_st, reports = REP.make_reports(
-                rep_st, slots, mask, now_, 0, flow_base, cfg)
-            # reporter id = shard (mod the schema's reporter id space);
-            # repack through the schema — no open-coded shifts here
-            wf = self.wire
-            rid = (shard % wf.n_reporters).astype(jnp.uint32)
-            mw = wf.report_meta_word
-            reports = reports.at[:, mw].set(
-                jnp.where(mask,
-                          wf.set_report_reporter(reports[:, mw], rid),
-                          0))
+            with jax.named_scope("reporter"):
+                with jax.named_scope("ingest"):
+                    rep_st = REP.ingest(rep_st, {"ts": ev_ts, "size": ev_sz,
+                                                 "five_tuple": ev_tu,
+                                                 "valid": ev_va}, cfg)
+                # 2. due flows -> DTA reports; ``due`` counts every due
+                # flow, the ones past the capacity cut included
+                with jax.named_scope("due"):
+                    due = jnp.sum(REP.due_mask(rep_st, now_, cfg))
+                    slots, mask = REP.due_flows(rep_st, now_, cfg,
+                                                cfg.report_capacity)
+                with jax.named_scope("reports"):
+                    rep_st, reports = REP.make_reports(
+                        rep_st, slots, mask, now_, 0, flow_base, cfg)
+                    # reporter id = shard (mod the schema's reporter id
+                    # space); repack through the schema — no open-coded
+                    # shifts here
+                    wf = self.wire
+                    rid = (shard % wf.n_reporters).astype(jnp.uint32)
+                    mw = wf.report_meta_word
+                    reports = reports.at[:, mw].set(
+                        jnp.where(mask,
+                                  wf.set_report_reporter(reports[:, mw],
+                                                         rid),
+                                  0))
             # 3. route to owner shards (fixed-capacity buckets + all_to_all)
-            buckets, bmask, mis = TRANS.route_reports(
-                reports, mask, n, cfg.flows_per_shard, cap_out)
-            routed = jax.lax.all_to_all(buckets, ax, 0, 0, tiled=True)
-            rmask = jax.lax.all_to_all(
-                bmask.astype(jnp.uint32), ax, 0, 0,
-                tiled=True).astype(bool)
-            dropped = jnp.sum(mask) - jnp.sum(bmask) - mis
-            routed = routed.reshape(n * cap_out, PROTO.REPORT_WORDS)
-            rmask = rmask.reshape(n * cap_out)
+            with jax.named_scope("route"):
+                buckets, bmask, mis = TRANS.route_reports(
+                    reports, mask, n, cfg.flows_per_shard, cap_out)
+                routed = jax.lax.all_to_all(buckets, ax, 0, 0, tiled=True)
+                rmask = jax.lax.all_to_all(
+                    bmask.astype(jnp.uint32), ax, 0, 0,
+                    tiled=True).astype(bool)
+                dropped = jnp.sum(mask) - jnp.sum(bmask) - mis
+                routed = routed.reshape(n * cap_out, PROTO.REPORT_WORDS)
+                rmask = rmask.reshape(n * cap_out)
             # 4. owner-side translator: history addresses + RoCEv2 payloads
-            tr_st, payloads, coords = TRANS.translate(
-                tr_st, routed, rmask, flow_base, cfg)
+            with jax.named_scope("translate"):
+                tr_st, payloads, coords = TRANS.translate(
+                    tr_st, routed, rmask, flow_base, cfg)
             # 5. collector ring placement (ring_scatter via dispatch),
             # optionally through the lossy-transport injector — faults
             # hit only what the collector sees (the RDMA segment);
@@ -462,18 +481,21 @@ class DFASystem:
             ing_pay, ing_mask = payloads, rmask
             fmetrics = {}
             if self.fault_spec is not None:
-                ing_pay, ing_mask, fcounts, fledger = FAULTS.inject(
-                    payloads, rmask, self.fault_spec, wf, now_, shard)
-                fmetrics = {k: jax.lax.psum(v, ax)
-                            for k, v in fcounts.items()}
-                fmetrics.update(fledger)
-            lseq0, recv0 = coll_st.last_seq, coll_st.received
-            coll_st = COLL.ingest(coll_st, ing_pay, ing_mask, flow_base,
-                                  cfg)
-            coll_st, lost_delta = _global_seq_gap(
-                coll_st, lseq0, recv0, lost0, shard, ax)
+                with jax.named_scope("faults"):
+                    ing_pay, ing_mask, fcounts, fledger = FAULTS.inject(
+                        payloads, rmask, self.fault_spec, wf, now_, shard)
+                    fmetrics = {k: jax.lax.psum(v, ax)
+                                for k, v in fcounts.items()}
+                    fmetrics.update(fledger)
+            with jax.named_scope("collector"):
+                lseq0, recv0 = coll_st.last_seq, coll_st.received
+                coll_st = COLL.ingest(coll_st, ing_pay, ing_mask, flow_base,
+                                      cfg)
+                coll_st, lost_delta = _global_seq_gap(
+                    coll_st, lseq0, recv0, lost0, shard, ax)
             metrics = {
                 "reports_sent": jax.lax.psum(jnp.sum(mask), ax),
+                "reports_due": jax.lax.psum(due, ax),
                 "reports_recv": jax.lax.psum(jnp.sum(rmask), ax),
                 "bucket_drops": jax.lax.psum(jnp.sum(dropped), ax),
                 "misroutes": jax.lax.psum(mis, ax),
@@ -606,54 +628,62 @@ class DFASystem:
                 reports. The global port id IS the reporter identity (mod
                 the schema's reporter id space) — stable across mesh
                 factorizations."""
-                pst = REP.ingest(pst, ev, self.rep_cfg)
-                slots, mask = REP.due_flows(pst, now_, self.rep_cfg, R_p)
-                rid = (gid % wf.n_reporters).astype(jnp.uint32)
-                if hrw:
-                    fids = TRANS.rendezvous_flow_ids(
-                        pst.keys[slots], nodes_arr, fps)
-                else:
-                    fids = TRANS.home_flow_ids(pst.keys[slots], G)
-                pst, reports = REP.make_reports(
-                    pst, slots, mask, now_, rid, 0, self.rep_cfg,
-                    flow_ids=fids)
-                return pst, reports, mask
+                with jax.named_scope("ingest"):
+                    pst = REP.ingest(pst, ev, self.rep_cfg)
+                with jax.named_scope("due"):
+                    due = jnp.sum(REP.due_mask(pst, now_, self.rep_cfg))
+                    slots, mask = REP.due_flows(pst, now_, self.rep_cfg,
+                                                R_p)
+                with jax.named_scope("reports"):
+                    rid = (gid % wf.n_reporters).astype(jnp.uint32)
+                    if hrw:
+                        fids = TRANS.rendezvous_flow_ids(
+                            pst.keys[slots], nodes_arr, fps)
+                    else:
+                        fids = TRANS.home_flow_ids(pst.keys[slots], G)
+                    pst, reports = REP.make_reports(
+                        pst, slots, mask, now_, rid, 0, self.rep_cfg,
+                        flow_ids=fids)
+                return pst, reports, mask, due
 
-            gids = dev * P_l + jnp.arange(P_l, dtype=jnp.int32)
-            stacked = REP.ReporterState(regs, last_ts, last_report, keys,
-                                        active, rep_st.seq,
-                                        rep_st.collisions)
-            ev_b = {"ts": ev_ts.reshape(P_l, E_p),
-                    "size": ev_sz.reshape(P_l, E_p),
-                    "five_tuple": ev_tu.reshape(P_l, E_p, 5),
-                    "valid": ev_va.reshape(P_l, E_p)}
-            if vmap_ports:
-                new_st, reports_s, masks_s = jax.vmap(port_body)(
-                    stacked, ev_b, gids)
-            else:
-                # unrolled loop for the pallas/interpret backends: the
-                # ingest path can resolve to the scalar-prefetch HBM
-                # pallas variant, which has no batching rule; P_l stays
-                # small there (kernel meshes host single-digit ports)
-                outs = [port_body(jax.tree.map(lambda a: a[p], stacked),
-                                  {k: v[p] for k, v in ev_b.items()},
-                                  gids[p])
-                        for p in range(P_l)]
-                new_st = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                      *[o[0] for o in outs])
-                reports_s = jnp.stack([o[1] for o in outs])
-                masks_s = jnp.stack([o[2] for o in outs])
-            rep_st = REP.ReporterState(
-                regs=new_st.regs.reshape(P_l * Rs, REP.N_REG),
-                last_ts=new_st.last_ts.reshape(P_l * Rs),
-                last_report=new_st.last_report.reshape(P_l * Rs),
-                keys=new_st.keys.reshape(P_l * Rs, 5),
-                active=new_st.active.reshape(P_l * Rs),
-                seq=new_st.seq,
-                collisions=new_st.collisions)
-            reports = reports_s.reshape(P_l * R_p, wf.report_words)
-            mask = masks_s.reshape(P_l * R_p)
-            sent = jnp.sum(mask)
+            with jax.named_scope("reporter"):
+                gids = dev * P_l + jnp.arange(P_l, dtype=jnp.int32)
+                stacked = REP.ReporterState(regs, last_ts, last_report, keys,
+                                            active, rep_st.seq,
+                                            rep_st.collisions)
+                ev_b = {"ts": ev_ts.reshape(P_l, E_p),
+                        "size": ev_sz.reshape(P_l, E_p),
+                        "five_tuple": ev_tu.reshape(P_l, E_p, 5),
+                        "valid": ev_va.reshape(P_l, E_p)}
+                if vmap_ports:
+                    new_st, reports_s, masks_s, dues = jax.vmap(port_body)(
+                        stacked, ev_b, gids)
+                else:
+                    # unrolled loop for the pallas/interpret backends: the
+                    # ingest path can resolve to the scalar-prefetch HBM
+                    # pallas variant, which has no batching rule; P_l stays
+                    # small there (kernel meshes host single-digit ports)
+                    outs = [port_body(jax.tree.map(lambda a: a[p], stacked),
+                                      {k: v[p] for k, v in ev_b.items()},
+                                      gids[p])
+                            for p in range(P_l)]
+                    new_st = jax.tree.map(lambda *xs: jnp.stack(xs),
+                                          *[o[0] for o in outs])
+                    reports_s = jnp.stack([o[1] for o in outs])
+                    masks_s = jnp.stack([o[2] for o in outs])
+                    dues = jnp.stack([o[3] for o in outs])
+                rep_st = REP.ReporterState(
+                    regs=new_st.regs.reshape(P_l * Rs, REP.N_REG),
+                    last_ts=new_st.last_ts.reshape(P_l * Rs),
+                    last_report=new_st.last_report.reshape(P_l * Rs),
+                    keys=new_st.keys.reshape(P_l * Rs, 5),
+                    active=new_st.active.reshape(P_l * Rs),
+                    seq=new_st.seq,
+                    collisions=new_st.collisions)
+                reports = reports_s.reshape(P_l * R_p, wf.report_words)
+                mask = masks_s.reshape(P_l * R_p)
+                sent = jnp.sum(mask)
+                due = jnp.sum(dues)
             # home-pod index from the flow word — a pure function, so
             # the ragged path can recompute it after its pre-merge sort
             if hrw:
@@ -664,93 +694,99 @@ class DFASystem:
                 def hpod_of(fid):
                     return TRANS.home_coords(fid, fps, S,
                                              self.n_shards)[0]
-            # stage 1: intra-pod all_to_all by home shard. The shard
-            # coordinate of even a corrupt flow id is in range (floor
-            # mod), so misroutes surface at stage 2 via the pod
-            # coordinate — mis1 is structurally zero and kept only so
-            # the accounting stays stage-symmetric.
-            if hrw:
-                pos1 = TRANS.node_position(
-                    reports[:, 0] // jnp.uint32(fps), nodes_arr)
-                hshard = pos1 % S
-            else:
-                _, hshard, _ = TRANS.home_coords(reports[:, 0], fps, S,
-                                                 self.n_shards)
-            b1, m1, mis1 = TRANS.route_by_dest(reports, mask, hshard, S,
-                                               cap1)
-            drop1 = sent - jnp.sum(m1) - mis1
-            if self.shard_axes:
-                b1 = jax.lax.all_to_all(b1, self.shard_axes, 0, 0,
-                                        tiled=True)
-                m1 = jax.lax.all_to_all(
-                    m1.astype(jnp.uint32), self.shard_axes, 0, 0,
-                    tiled=True).astype(bool)
-            r1 = b1.reshape(S * cap1, PROTO.REPORT_WORDS)
-            m1 = m1.reshape(S * cap1)
-            # stage 2: cross-pod exchange by home pod
-            extra = {}
-            if ragged:
-                # compact exchange: pod-local rows never cross, remote
-                # rows are pre-merged (flow-major) and packed into
-                # cap2c-row segments — only the occupied capacity moves
-                # over the scarce inter-pod link
-                (lrows, lmask, b2, m2, mis2,
-                 nmsg) = TRANS.crosspod_compact(
-                    r1, m1, pod, pods, cap2c, hpod_of, wire=wf)
-                crosspod_sent = jnp.sum(m2)
-                drop2 = (jnp.sum(m1) - jnp.sum(lmask) - crosspod_sent
-                         - mis2)
-                if self.pod_axis is not None:
-                    b2 = jax.lax.all_to_all(b2, self.pod_axis, 0, 0,
+            with jax.named_scope("route"):
+                # stage 1: intra-pod all_to_all by home shard. The shard
+                # coordinate of even a corrupt flow id is in range (floor
+                # mod), so misroutes surface at stage 2 via the pod
+                # coordinate — mis1 is structurally zero and kept only so
+                # the accounting stays stage-symmetric.
+                if hrw:
+                    pos1 = TRANS.node_position(
+                        reports[:, 0] // jnp.uint32(fps), nodes_arr)
+                    hshard = pos1 % S
+                else:
+                    _, hshard, _ = TRANS.home_coords(reports[:, 0], fps, S,
+                                                     self.n_shards)
+                b1, m1, mis1 = TRANS.route_by_dest(reports, mask, hshard, S,
+                                                   cap1)
+                drop1 = sent - jnp.sum(m1) - mis1
+                if self.shard_axes:
+                    b1 = jax.lax.all_to_all(b1, self.shard_axes, 0, 0,
                                             tiled=True)
-                    m2 = jax.lax.all_to_all(
-                        m2.astype(jnp.uint32), self.pod_axis, 0, 0,
+                    m1 = jax.lax.all_to_all(
+                        m1.astype(jnp.uint32), self.shard_axes, 0, 0,
                         tiled=True).astype(bool)
-                routed = jnp.concatenate(
-                    [lrows,
-                     b2.reshape(pods * cap2c, PROTO.REPORT_WORDS)])
-                rmask = jnp.concatenate(
-                    [lmask, m2.reshape(pods * cap2c)])
-                extra = {
-                    "crosspod_sent": jax.lax.psum(crosspod_sent, ax),
-                    "crosspod_messages": jax.lax.psum(nmsg, ax)}
-            else:
-                b2, m2, mis2 = TRANS.route_by_dest(
-                    r1, m1, hpod_of(r1[:, 0]), pods, cap2)
-                drop2 = jnp.sum(m1) - jnp.sum(m2) - mis2
-                if self.pod_axis is not None:
-                    b2 = jax.lax.all_to_all(b2, self.pod_axis, 0, 0,
-                                            tiled=True)
-                    m2 = jax.lax.all_to_all(
-                        m2.astype(jnp.uint32), self.pod_axis, 0, 0,
-                        tiled=True).astype(bool)
-                routed = b2.reshape(pods * cap2, PROTO.REPORT_WORDS)
-                rmask = m2.reshape(pods * cap2)
-            # home-side canonical arrival order (mesh-shape independent:
-            # the ragged path's local/received split and the padded
-            # path's bucket interleaving both collapse to the same
-            # (flow, reporter, seq) total order)
-            routed, rmask = TRANS.canonical_order(routed, rmask, wire=wf)
-            # owner-side translator + ring placement, as in the 1D path
-            tr_st, payloads, coords = TRANS.translate(
-                tr_st, routed, rmask, flow_base, cfg)
+                r1 = b1.reshape(S * cap1, PROTO.REPORT_WORDS)
+                m1 = m1.reshape(S * cap1)
+            with jax.named_scope("exchange"):
+                # stage 2: cross-pod exchange by home pod
+                extra = {}
+                if ragged:
+                    # compact exchange: pod-local rows never cross, remote
+                    # rows are pre-merged (flow-major) and packed into
+                    # cap2c-row segments — only the occupied capacity moves
+                    # over the scarce inter-pod link
+                    (lrows, lmask, b2, m2, mis2,
+                     nmsg) = TRANS.crosspod_compact(
+                        r1, m1, pod, pods, cap2c, hpod_of, wire=wf)
+                    crosspod_sent = jnp.sum(m2)
+                    drop2 = (jnp.sum(m1) - jnp.sum(lmask) - crosspod_sent
+                             - mis2)
+                    if self.pod_axis is not None:
+                        b2 = jax.lax.all_to_all(b2, self.pod_axis, 0, 0,
+                                                tiled=True)
+                        m2 = jax.lax.all_to_all(
+                            m2.astype(jnp.uint32), self.pod_axis, 0, 0,
+                            tiled=True).astype(bool)
+                    routed = jnp.concatenate(
+                        [lrows,
+                         b2.reshape(pods * cap2c, PROTO.REPORT_WORDS)])
+                    rmask = jnp.concatenate(
+                        [lmask, m2.reshape(pods * cap2c)])
+                    extra = {
+                        "crosspod_sent": jax.lax.psum(crosspod_sent, ax),
+                        "crosspod_messages": jax.lax.psum(nmsg, ax)}
+                else:
+                    b2, m2, mis2 = TRANS.route_by_dest(
+                        r1, m1, hpod_of(r1[:, 0]), pods, cap2)
+                    drop2 = jnp.sum(m1) - jnp.sum(m2) - mis2
+                    if self.pod_axis is not None:
+                        b2 = jax.lax.all_to_all(b2, self.pod_axis, 0, 0,
+                                                tiled=True)
+                        m2 = jax.lax.all_to_all(
+                            m2.astype(jnp.uint32), self.pod_axis, 0, 0,
+                            tiled=True).astype(bool)
+                    routed = b2.reshape(pods * cap2, PROTO.REPORT_WORDS)
+                    rmask = m2.reshape(pods * cap2)
+            with jax.named_scope("translate"):
+                # home-side canonical arrival order (mesh-shape independent:
+                # the ragged path's local/received split and the padded
+                # path's bucket interleaving both collapse to the same
+                # (flow, reporter, seq) total order)
+                routed, rmask = TRANS.canonical_order(routed, rmask, wire=wf)
+                # owner-side translator + ring placement, as in the 1D path
+                tr_st, payloads, coords = TRANS.translate(
+                    tr_st, routed, rmask, flow_base, cfg)
             # optional lossy-transport injector on the collector-facing
             # stream only (see the 1D path for the rationale)
             ing_pay, ing_mask = payloads, rmask
             fmetrics = {}
             if self.fault_spec is not None:
-                ing_pay, ing_mask, fcounts, fledger = FAULTS.inject(
-                    payloads, rmask, self.fault_spec, wf, now_, dev)
-                fmetrics = {k: jax.lax.psum(v, ax)
-                            for k, v in fcounts.items()}
-                fmetrics.update(fledger)
-            lseq0, recv0 = coll_st.last_seq, coll_st.received
-            coll_st = COLL.ingest(coll_st, ing_pay, ing_mask, flow_base,
-                                  cfg)
-            coll_st, lost_delta = _global_seq_gap(
-                coll_st, lseq0, recv0, lost0, dev, ax)
+                with jax.named_scope("faults"):
+                    ing_pay, ing_mask, fcounts, fledger = FAULTS.inject(
+                        payloads, rmask, self.fault_spec, wf, now_, dev)
+                    fmetrics = {k: jax.lax.psum(v, ax)
+                                for k, v in fcounts.items()}
+                    fmetrics.update(fledger)
+            with jax.named_scope("collector"):
+                lseq0, recv0 = coll_st.last_seq, coll_st.received
+                coll_st = COLL.ingest(coll_st, ing_pay, ing_mask, flow_base,
+                                      cfg)
+                coll_st, lost_delta = _global_seq_gap(
+                    coll_st, lseq0, recv0, lost0, dev, ax)
             metrics = {
                 "reports_sent": jax.lax.psum(sent, ax),
+                "reports_due": jax.lax.psum(due, ax),
                 "reports_recv": jax.lax.psum(jnp.sum(rmask), ax),
                 "bucket_drops": jax.lax.psum(drop1 + drop2, ax),
                 "misroutes": jax.lax.psum(mis1 + mis2, ax),
@@ -802,8 +838,10 @@ class DFASystem:
         ax = self.axes
 
         def local(coll_st, lf, fid, m):
-            enriched = COLL.enrich_flow_history(coll_st, lf, cfg, mask=m)
-            flow_ids = jnp.where(m, fid, jnp.uint32(WIRE.PAD_FLOW_ID))
+            with jax.named_scope("enrich"):
+                enriched = COLL.enrich_flow_history(coll_st, lf, cfg,
+                                                    mask=m)
+                flow_ids = jnp.where(m, fid, jnp.uint32(WIRE.PAD_FLOW_ID))
             return enriched, flow_ids, m
 
         specs = self.state_specs()
@@ -817,8 +855,9 @@ class DFASystem:
         if self.infer_fn is not None:
             # the hook consumes the features in the same trace — "features
             # land in device memory and are consumed in the same period"
-            preds = self.infer_fn(enriched)
-            preds = jnp.where(emask[:, None], preds, 0.0)
+            with jax.named_scope("enrich"), jax.named_scope("infer"):
+                preds = self.infer_fn(enriched)
+                preds = jnp.where(emask[:, None], preds, 0.0)
         return enriched, flow_ids, emask, preds
 
     def dfa_step(self, state: DFAState, events: Dict[str, jax.Array],

@@ -249,20 +249,32 @@ def _ingest_multipass(state: ReporterState, slots: jax.Array,
     return state._replace(regs=regs, last_ts=new_last)
 
 
-def due_flows(state: ReporterState, now: jax.Array, cfg: DFAConfig,
-              capacity: int) -> Tuple[jax.Array, jax.Array]:
-    """Flows whose monitoring period elapsed (paper: per-flow configurable
-    interval; we use the global default with a per-flow offset hook).
-
-    Returns (slots (capacity,) i32, mask (capacity,) bool) — fixed-size for
-    SPMD; selection is by largest elapsed time (most-overdue-first).
+def due_mask(state: ReporterState, now: jax.Array,
+             cfg: DFAConfig) -> jax.Array:
+    """(F,) bool: the active flows whose monitoring period elapsed at
+    ``now`` — every flow due, before :func:`due_flows` cuts them to its
+    capacity.
 
     The elapsed compare is u32-subtraction based, so it stays correct
     across µs-clock wrap (now < last_report numerically still yields the
     true elapsed interval mod 2^32).
     """
     elapsed = (now - state.last_report).astype(jnp.uint32)
-    due = state.active & (elapsed >= jnp.uint32(cfg.monitoring_period_us))
+    return state.active & (elapsed >= jnp.uint32(cfg.monitoring_period_us))
+
+
+def due_flows(state: ReporterState, now: jax.Array, cfg: DFAConfig,
+              capacity: int) -> Tuple[jax.Array, jax.Array]:
+    """Flows whose monitoring period elapsed (paper: per-flow configurable
+    interval; we use the global default with a per-flow offset hook).
+
+    Returns (slots (capacity,) i32, mask (capacity,) bool) — fixed-size for
+    SPMD; selection is by largest elapsed time (most-overdue-first) among
+    the :func:`due_mask` flows; the due flows past ``capacity`` wait for a
+    later period.
+    """
+    elapsed = (now - state.last_report).astype(jnp.uint32)
+    due = due_mask(state, now, cfg)
     if cfg.monitoring_period_us == 0:
         # elapsed can be 0 for a genuinely due flow; |1 keeps its score
         # above every not-due slot so top_k cannot displace it

@@ -17,11 +17,23 @@ arrays, a fixed T, offline streaming. This is the real serving driver:
     percentiles; exact drop accounting; graceful drain on shutdown.
 
 Latency methodology: one sample per period, measured on the host from
-step dispatch to ``jax.block_until_ready`` on that period's outputs —
-i.e. the full verdict latency a consumer observes, including the
-overlapped upload of the next period's events. Percentiles use
-``np.percentile`` linear interpolation (tested against hand-computed
-samples in tests/test_serving.py).
+step dispatch to ``jax.block_until_ready`` on that period's outputs,
+including the overlapped build and upload of the next period's events.
+It leaves out the period's own batch: its build, its upload and its
+wait in the ingest ring for the previous step to finish, which under
+load can take as long as the sample itself (the ``serve/*`` spans below
+time those). Percentiles use ``np.percentile`` linear
+interpolation (tested against hand-computed samples in
+tests/test_serving.py).
+
+Tracing: every phase of the loop is a ``jax.profiler.TraceAnnotation``
+named ``serve/<phase>`` — ``next_batch``, ``stage``, ``dispatch``,
+``wait`` (the ``block_until_ready``), ``snapshot`` and ``recover`` —
+carrying ``period=k``, the index of the period it serves (its place in
+``ServingReport.latency_us`` and ``per_period``), so one period's spans
+join across loop iterations. They cost nothing without a profiler; under
+``jax.profiler.trace`` they sit on the host plane, on the device ops'
+clock.
 
 Backpressure: the source paces arrivals in virtual time (one budget per
 period — deterministic; see data.replay), so offering faster than the
@@ -46,6 +58,11 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 from repro.data.replay import PeriodAccounting, TraceReplaySource
+
+
+def _span(phase: str, period: int):
+    """The profiler span of one loop phase for period ``period``."""
+    return jax.profiler.TraceAnnotation(f"serve/{phase}", period=period)
 
 
 def latency_summary(samples_us) -> Dict[str, float]:
@@ -125,15 +142,6 @@ class ServingReport:
     def balanced(self) -> bool:
         """The exact-accounting invariant (always true after a drain)."""
         return self.offered == self.processed + self.dropped
-
-    @property
-    def sustained_eps(self) -> float:
-        """Events actually served per second of budgeted period time
-        (0.0 for a zero-period run — no time was budgeted)."""
-        total = self.periods + self.drained_periods
-        if total == 0:
-            return 0.0
-        return self.processed / (total * self.budget_us / 1e6)
 
 
 def build_source(system, events, nows=None,
@@ -284,8 +292,8 @@ class ServingLoop:
         if periods == 0:
             # explicit empty run: nothing offered, nothing measured —
             # the report carries the empty latency summary (count=0,
-            # NaN percentiles) and a 0.0 sustained rate, so callers that
-            # size their period count dynamically never divide by zero
+            # NaN percentiles), so callers that size their period count
+            # dynamically never divide by zero
             total = self.source.total
             return ServingReport(
                 periods=0, drained_periods=0, budget_us=self.budget_us,
@@ -312,14 +320,18 @@ class ServingLoop:
         if snap_on:
             from repro.checkpoint import checkpoint as CKPT
 
-        batch, now, acct = source.next_batch()      # period 0, staged
-        staged = self.ring.stage(batch, now)        # before the loop
+        with _span("next_batch", 0):
+            batch, now, acct = source.next_batch()  # period 0, staged
+        with _span("stage", 0):
+            staged = self.ring.stage(batch, now)    # before the loop
         self._journal.append((1, batch, now))       # consumed by period 1
         t = 0
         while True:
             accounts.append(acct)
+            k = t                                   # the period served
             t0 = time.perf_counter()
-            out = self._step(state, *staged)        # async dispatch
+            with _span("dispatch", k):
+                out = self._step(state, *staged)    # async dispatch
             # pull + stage period t+1 while t computes (the overlap)
             t += 1
             if t >= periods and drain:
@@ -327,13 +339,16 @@ class ServingLoop:
             has_next = (t < periods
                         or (drain and source.pending > 0))
             if has_next:
-                batch, now, acct = source.next_batch()
-                staged = self.ring.stage(batch, now)
+                with _span("next_batch", t):
+                    batch, now, acct = source.next_batch()
+                with _span("stage", t):
+                    staged = self.ring.stage(batch, now)
                 self._journal.append((t + 1, batch, now))
                 if t >= periods:
                     drained += 1
             state = out.state
-            jax.block_until_ready(out)              # period t-1 done
+            with _span("wait", k):
+                jax.block_until_ready(out)          # period k done
             lat_us = (time.perf_counter() - t0) * 1e6
             latencies.append(lat_us)
             if lat_us > self.budget_us:
@@ -344,8 +359,10 @@ class ServingLoop:
                 # yet: save() copies to host synchronously here, then the
                 # writer thread owns the IO. The final period always
                 # snapshots, so a drain never strands a partial window.
-                th = CKPT.save(state, self.snapshot_dir, step=t,
-                               keep=system.cfg.snapshot_keep, async_=True)
+                with _span("snapshot", k):
+                    th = CKPT.save(state, self.snapshot_dir, step=t,
+                                   keep=system.cfg.snapshot_keep,
+                                   async_=True)
                 if th is not None:
                     snap_threads.append(th)
                 snapshots += 1
@@ -357,7 +374,8 @@ class ServingLoop:
                     th.join()
                 snap_threads.clear()
                 stall0 = time.perf_counter()
-                state, replayed = self._recover(dead, t)
+                with _span("recover", k):
+                    state, replayed = self._recover(dead, t)
                 stalls.append((time.perf_counter() - stall0) * 1e6)
                 recoveries += 1
                 replayed_total += replayed
@@ -367,7 +385,8 @@ class ServingLoop:
                     # re-stage on the survivor ring (it is also in the
                     # journal, but replay stops at t — the pending
                     # period t+1 runs in the normal loop path)
-                    staged = self.ring.stage(batch, now)
+                    with _span("stage", t):
+                        staged = self.ring.stage(batch, now)
             if not has_next:
                 break
 
