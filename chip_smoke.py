@@ -8,8 +8,15 @@ serves a seeded trace through ``launch/serving.py`` (``build_source`` +
 ``ServingLoop.run``). The script checks the serving report, that the
 compiled step holds the Pallas kernels of the main path, and then, in the
 same process, replays the trace with ``kernel_backend="ref"``: integer
-state must agree bit for bit, enriched features within the per-row
-tolerance of ``tests/test_gather_enrich_equiv.py``.
+state must agree bit for bit, and in every period the collector's ring and
+validity bitwise and the enriched features within the per-row tolerance of
+``tests/test_gather_enrich_equiv.py``. Then ``ring_scatter`` alone at the
+PAPER shapes (F = 2^17, R = 65,536) on adversarial coordinates — repeated
+(flow, hist), every report in one tile, masked rows at the ring's edges —
+must equal ``ring_scatter_ref`` (run on the host's CPU, where a scatter
+applies its updates in order) bit for bit. Last, the enrichment's
+division (``enrich.div_rn``, in a kernel and in XLA) must equal IEEE
+division, computed by numpy on the host, bit for bit.
 
 ``--chips 4``: one seeded trace streams through a (2, 2)
 ``make_dfa_mesh`` system (``flow_home="hash"``, PAPER per-shard sizes),
@@ -28,6 +35,7 @@ from __future__ import annotations
 import argparse
 import concurrent.futures
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -57,6 +65,7 @@ N_FLOWS = 4096
 PERIODS = 16
 SEED = 0
 MAIN_PATH_KERNELS = ("ingest_update", "ring_scatter", "gather_enrich_hbm")
+SCATTER_REPORTS = 65536   # the benchmark's report_capacity
 MESH_PORTS = 4            # reporter ports of the mesh run: 1 per chip
 MESH_SHARDS = 4           # the (2, 2) mesh; G = 4 x PAPER per-shard flows
 
@@ -137,9 +146,34 @@ def assert_rows_close(got, want, ctx: str) -> float:
 
 # -- one chip -----------------------------------------------------------------
 
-def serve(system, events, nows, warm: bool):
+class PeriodTap:
+    """The system, with every period's ring, validity and outputs copied
+    to the host before the next (donating) step consumes the state."""
+
+    def __init__(self, inner):
+        self._inner = inner
+        self.periods = []
+
+    def jit_step(self, donate: bool = True):
+        step = self._inner.jit_step(donate)
+
+        def tapped(state, events, now):
+            out = step(state, events, now)
+            coll = out.state.collector
+            self.periods.append(host_tree({
+                "memory": coll.memory, "entry_valid": coll.entry_valid,
+                "mask": out.mask, "flow_ids": out.flow_ids,
+                "enriched": out.enriched}))
+            return out
+        return tapped
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+
+def serve(system, events, nows, warm: bool, tap=None):
     source = build_source(system, events, nows)
-    loop = ServingLoop(system, source)
+    loop = ServingLoop(tap or system, source)
     if warm:
         # one step outside the served window, so the loop's latency
         # samples hold no compile (the jit is the one the loop uses)
@@ -181,7 +215,8 @@ def one_chip() -> None:
     log("tpu_custom_call found for " + ", ".join(MAIN_PATH_KERNELS))
 
     events, nows = trace(1, cfg.event_block)
-    rep = serve(system, events, nows, warm=True)
+    tap = PeriodTap(system)
+    rep = serve(system, events, nows, warm=True, tap=tap)
     last = rep.last
     stats = devices[0].memory_stats() or {}
     log(f"peak_bytes_in_use: {stats.get('peak_bytes_in_use', 'n/a')}")
@@ -206,7 +241,8 @@ def one_chip() -> None:
 
     ref_system = DFASystem(dataclasses.replace(cfg, kernel_backend="ref"),
                            mesh)
-    ref = serve(ref_system, events, nows, warm=False)
+    ref_tap = PeriodTap(ref_system)
+    ref = serve(ref_system, events, nows, warm=False, tap=ref_tap)
     check((ref.offered, ref.processed, ref.dropped)
           == (rep.offered, rep.processed, rep.dropped),
           "ref replay saw different event accounting")
@@ -224,9 +260,106 @@ def one_chip() -> None:
     err = assert_rows_close(enriched[mask],
                             np.asarray(ref.last.enriched)[mask],
                             "pallas vs ref")
+    check(len(tap.periods) == len(ref_tap.periods) >= PERIODS,
+          f"periods served: pallas {len(tap.periods)}, ref "
+          f"{len(ref_tap.periods)}")
+    for t, (got, want) in enumerate(zip(tap.periods, ref_tap.periods)):
+        for k in ("memory", "entry_valid", "mask", "flow_ids"):
+            off = int((got[k] != want[k]).sum())
+            check(off == 0, f"pallas vs ref: period {t} {k}: {off} "
+                  f"words differ")
+        m = want["mask"]
+        err = max(err, assert_rows_close(got["enriched"][m],
+                                         want["enriched"][m],
+                                         f"pallas vs ref, period {t}"))
     log(f"pallas == ref: integer state bitwise, last-period metrics "
-        f"bitwise, features within {err:.3e} of the row scale "
-        f"(tolerance {FEATURE_TOL:g})")
+        f"bitwise; in each of {len(tap.periods)} periods ring, validity, "
+        f"masks and flow ids bitwise, features within {err:.3e} of the "
+        f"row scale (tolerance {FEATURE_TOL:g})")
+    ring_scatter_adversarial()
+    division_rounding()
+
+
+def division_rounding() -> None:
+    """The enrichment's divisions on the chip against IEEE division
+    (numpy on the host), bit for bit, on operands like the features':
+    integer sums over counts, tiny moments over EPS, sums of squares
+    near the largest float over the window's entry count. The chip's
+    native quotient is counted too, for the record."""
+    from jax.experimental import pallas as pl
+
+    from repro.core import enrich as E
+    rng = np.random.default_rng(SEED)
+    n = 1 << 20
+    s = rng.integers(0, 2**32, size=n).astype(np.float32)
+    cnt = rng.integers(1, 2**20, size=n).astype(np.float32)
+    m3 = (rng.standard_normal(n) * 1e28).astype(np.float32)
+    big = (np.float32(3.4e38) * rng.random(n)).astype(np.float32)
+    nv = rng.integers(1, 11, size=n).astype(np.float32)
+    a = np.concatenate([s, m3, big]).reshape(-1, 1024)
+    b = np.concatenate([cnt, np.full(n, E.EPS, np.float32), nv]
+                       ).reshape(-1, 1024)
+    want = (a / b).view(np.int32)
+
+    def kernel(a_ref, b_ref, rn_ref, native_ref):
+        rn_ref[...] = E.div_rn(a_ref[...], b_ref[...])
+        native_ref[...] = a_ref[...] / b_ref[...]
+
+    spec = pl.BlockSpec((32, 1024), lambda i: (i, 0))
+    rn, native = jax.jit(lambda a, b: pl.pallas_call(
+        kernel, grid=(a.shape[0] // 32,), in_specs=[spec, spec],
+        out_specs=[spec, spec],
+        out_shape=[jax.ShapeDtypeStruct(a.shape, np.float32)] * 2,
+        interpret=dispatch.interpret_flag("pallas"),
+        name="division_rounding")(a, b))(a, b)
+    xla = jax.jit(E.div_rn)(a, b)
+    off = {k: int((np.asarray(v).view(np.int32) != want).sum())
+           for k, v in (("kernel", rn), ("xla", xla), ("native", native))}
+    check(off["kernel"] == 0 and off["xla"] == 0,
+          f"div_rn differs from IEEE division: {off}")
+    log(f"div_rn == IEEE division bitwise on {want.size} quotients, in a "
+        f"kernel and in XLA; the native kernel quotient differs in "
+        f"{off['native']}")
+
+
+def ring_scatter_adversarial() -> None:
+    """``ring_scatter`` compiled at the PAPER ring and the benchmark's
+    report count against the plain scatter, on coordinates that stress
+    report order and tile ranges."""
+    from repro.kernels.ring_scatter.kernel import ring_scatter_pallas
+    from repro.kernels.ring_scatter.ref import ring_scatter_ref
+    F, H, tile = PAPER.flows_per_shard, PAPER.history, PAPER.flow_tile
+    R = SCATTER_REPORTS
+    rng = np.random.default_rng(SEED)
+    mem = rng.integers(0, 2**32, size=(F, H, 16),
+                       dtype=np.uint64).astype(np.uint32)
+    few = rng.choice(F * H, size=1000, replace=False)    # repeated coords
+    edge = np.where(rng.random(R) > 0.5, 0, F - 1)       # clipped flows
+    half = rng.random(R) > 0.5
+    pick = few[rng.integers(0, few.size, size=R)]
+    cases = {
+        "repeats": (pick // H, pick % H, np.ones(R, bool)),
+        "one_tile": (F // 2 + rng.integers(0, tile, size=R),
+                     rng.integers(0, H, size=R), rng.random(R) > 0.1),
+        "masked_edges": (np.where(half, pick // H, edge), pick % H, half),
+    }
+    scatter = jax.jit(functools.partial(
+        ring_scatter_pallas, flow_tile=tile, history=H,
+        interpret=dispatch.interpret_flag("pallas")))
+    cpu = jax.devices("cpu")[0]
+    for name, (flow, hist, mask) in cases.items():
+        flow, hist = flow.astype(np.int32), hist.astype(np.int32)
+        pay = rng.integers(0, 2**32, size=(R, 16),
+                           dtype=np.uint64).astype(np.uint32)
+        got = np.asarray(scatter(mem, pay, flow, hist, mask))
+        with jax.default_device(cpu):
+            want = np.asarray(ring_scatter_ref(*map(
+                jax.numpy.asarray, (mem, pay, flow, hist, mask))))
+        off = int((got != want).sum())
+        check(off == 0, f"ring_scatter {name}: {off} words differ from "
+              f"ring_scatter_ref")
+        log(f"ring_scatter {name} (F={F}, R={R}, {int(mask.sum())} "
+            f"unmasked) == ring_scatter_ref bitwise")
 
 
 # -- four chips: the (pod, shard) mesh ----------------------------------------

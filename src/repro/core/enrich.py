@@ -33,6 +33,79 @@ def u32_to_f32(x: jax.Array) -> jax.Array:
     return hi * 65536.0 + lo
 
 
+def _nudge(ma, mb, ea, eb, mq, eq):
+    """One rounding step for a quotient ``mq * 2^eq`` of ``ma * 2^ea``
+    over ``mb * 2^eb`` (24-bit mantissas, biased exponents): the nearest
+    of it and its two neighbours. D = ma * 2^k - mq * mb is the exact
+    remainder in units where one step of ``mq`` is ``mb``, formed from
+    12-bit limbs so that every product fits in int32."""
+    k = ea - eq - eb + 150
+    sh = 24 - k
+    hi_sh = jnp.maximum(sh, 0)
+    x_hi = jnp.where(sh > 0, ma >> hi_sh, ma << jnp.maximum(-sh, 0))
+    x_lo = jnp.where(sh > 0, (ma & ((1 << hi_sh) - 1)) << jnp.minimum(k, 23),
+                     0)
+    q1, q0, b1, b0 = mq >> 12, mq & 0xFFF, mb >> 12, mb & 0xFFF
+    mid = q1 * b0 + q0 * b1
+    d_hi = x_hi - (q1 * b1 + (mid >> 12))
+    d = d_hi * 0x1000000 + x_lo - (((mid & 0xFFF) << 12) + q0 * b0)
+    ok = (k >= 22) & (k <= 25) & (jnp.abs(d_hi) <= 4)
+    low = mq == 0x800000                   # a power of two: finer step below
+    up = ok & (2 * d > mb)
+    down = ok & (2 * d << jnp.where(low, 1, 0) < -mb)
+    mq = mq + jnp.where(up, 1, 0) - jnp.where(down, 1, 0)
+    wrap_up, wrap_down = mq == 0x1000000, mq == 0x7FFFFF
+    mq = jnp.where(wrap_up, 0x800000, jnp.where(wrap_down, 0xFFFFFF, mq))
+    eq = eq + jnp.where(wrap_up, 1, 0) - jnp.where(wrap_down, 1, 0)
+    return mq, eq
+
+
+@jax.jit                         # eager callers compile it once a shape
+def div_rn(a: jax.Array, b: jax.Array) -> jax.Array:
+    """f32 ``a / b`` rounded to nearest even, as IEEE division rounds.
+
+    A TPU's f32 division lands one unit in the last place off for some
+    operands. The skews below subtract nearly equal moments, so one unit
+    in a mean can move a skew by orders of magnitude, and with it
+    whether a window's sum of squared skews overflows. So the native
+    quotient is checked against the exact remainder, in integers, and
+    moved to the nearer neighbour where it is off (twice, for a quotient
+    up to two units off). Operands or quotients outside the normal range
+    (zeros, infinities, NaNs, subnormals) keep the native quotient,
+    except that a quotient rounding past the largest float is
+    infinite."""
+    a, b = jnp.broadcast_arrays(jnp.asarray(a, jnp.float32),
+                                jnp.asarray(b, jnp.float32))
+    return round_quotient(a, b, a / b)
+
+
+def round_quotient(a: jax.Array, b: jax.Array, q: jax.Array) -> jax.Array:
+    """:func:`div_rn` from a quotient ``q`` of ``a / b`` that may be up
+    to two units in the last place off (f32 arrays of one shape)."""
+    ia, ib, iq = (jax.lax.bitcast_convert_type(x, jnp.int32)
+                  for x in (a, b, q))
+    ea, eb, eq = (ia >> 23) & 0xFF, (ib >> 23) & 0xFF, (iq >> 23) & 0xFF
+    ma, mb, mq = ((x & 0x7FFFFF) | 0x800000 for x in (ia, ib, iq))
+    normal = ((ea > 0) & (ea < 255) & (eb > 0) & (eb < 255)
+              & (eq > 0) & (eq < 255))
+    for _ in range(2):
+        mq, eq = _nudge(ma, mb, ea, eb, mq, eq)
+    bits = (iq & jnp.int32(-2**31)) | jnp.where(
+        eq < 255, (eq << 23) | (mq & 0x7FFFFF), 0x7F800000)
+    return jnp.where(normal & (eq > 0),
+                     jax.lax.bitcast_convert_type(bits, jnp.float32), q)
+
+
+def _div_stacked(nums: list, dens: list) -> list:
+    """:func:`div_rn` of each pair of ``nums`` and ``dens`` (arrays of one
+    shape, or scalars) as one division over the stacked operands: one
+    copy of the rounding in the program, not one per quotient."""
+    ops = jnp.broadcast_arrays(*nums, *dens)
+    k = len(nums)
+    q = div_rn(jnp.stack(ops[:k]), jnp.stack(ops[k:]))
+    return [q[j] for j in range(k)]
+
+
 def entry_feature_list(s) -> list:
     """Seven f32 Table-I register arrays (any common shape) -> the
     PER_ENTRY derived features, as a list of arrays of that shape.
@@ -40,27 +113,26 @@ def entry_feature_list(s) -> list:
     Moment identities: mean = S1/n, var = S2/n - mean², skew via S3
     (all on the log*-approximated sums, like Marina's CPU stage). Shared
     by :func:`entry_features` and the Pallas kernel, which works on one
-    (H, T) plane per register.
+    (H, T) plane per register. Every division rounds as IEEE's
+    (:func:`div_rn`).
     """
     n = jnp.maximum(s[0], 1.0)
-    iat1, iat2, iat3 = s[1], s[2], s[3]
-    ps1, ps2, ps3 = s[4], s[5], s[6]
-
-    def moments(s1, s2, s3):
-        mean = s1 / n
-        var = jnp.maximum(s2 / n - mean ** 2, 0.0)
-        std = jnp.sqrt(var)
-        cov = std / jnp.maximum(mean, EPS)
-        m3 = s3 / n - 3 * mean * var - mean ** 3
-        skew = m3 / jnp.maximum(std ** 3, EPS)
-        return mean, var, std, cov, skew
-
-    i_mean, i_var, i_std, i_cov, i_skew = moments(iat1, iat2, iat3)
-    p_mean, p_var, p_std, p_cov, p_skew = moments(ps1, ps2, ps3)
-    duration = jnp.maximum(iat1, 1.0)                    # µs total
-    volume = ps1                                         # bytes
-    rate_bps = volume * 8.0 / (duration / 1e6 + EPS)
-    pps = n / (duration / 1e6 + EPS)
+    duration = jnp.maximum(s[1], 1.0)                    # µs total
+    volume = s[4]                                        # bytes
+    # two rounds of divisions, each one div_rn over stacked operands
+    i_mean, i_s2, i_s3, p_mean, p_s2, p_s3, secs = _div_stacked(
+        list(s[1:7]) + [duration], [n] * 6 + [1e6])
+    secs = secs + EPS
+    i_var = jnp.maximum(i_s2 - i_mean ** 2, 0.0)
+    p_var = jnp.maximum(p_s2 - p_mean ** 2, 0.0)
+    i_std, p_std = jnp.sqrt(i_var), jnp.sqrt(p_var)
+    i_m3 = i_s3 - 3 * i_mean * i_var - i_mean ** 3
+    p_m3 = p_s3 - 3 * p_mean * p_var - p_mean ** 3
+    i_cov, p_cov, i_skew, p_skew, rate_bps, pps = _div_stacked(
+        [i_std, p_std, i_m3, p_m3, volume * 8.0, n],
+        [jnp.maximum(i_mean, EPS), jnp.maximum(p_mean, EPS),
+         jnp.maximum(i_std ** 3, EPS), jnp.maximum(p_std ** 3, EPS),
+         secs, secs])
     return [n, i_mean, i_var, i_std, i_cov, i_skew,
             p_mean, p_var, p_std, p_cov, p_skew,
             volume, rate_bps, pps, duration,
@@ -98,11 +170,11 @@ def derive_ref(memory_entries: jax.Array, entry_valid: jax.Array,
     newest = jnp.argmax(count, axis=-1)                  # (F,)
     newest_f = jnp.take_along_axis(
         feats, newest[:, None, None].repeat(PER_ENTRY, -1), axis=1)[:, 0]
-    mean_w = feats.sum(1) / nvalid
+    mean_w = div_rn(feats.sum(1), nvalid)
     # two-pass (masked) variance: E[(x-mean)^2] avoids the E[x^2]-mean^2
     # cancellation, keeping ref and kernel paths within 1e-5 relative
     dev = (feats - mean_w[:, None, :]) * vmask
-    var_w = (dev * dev).sum(1) / nvalid
+    var_w = div_rn((dev * dev).sum(1), nvalid)
     std_w = jnp.sqrt(var_w)
     delta = newest_f - mean_w
     maxhist = jnp.max(jnp.where(entry_valid, hist_idx.astype(jnp.float32),

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import wire as WIRE
-from repro.core.enrich import entry_feature_list, u32_to_f32
+from repro.core.enrich import div_rn, entry_feature_list, u32_to_f32
 
 WORDS = 16
 
@@ -51,7 +51,7 @@ def derive_rows(word: Callable[[int], jax.Array], valid: jax.Array,
     plane; ``valid`` is the (H, T) entry validity. Returns derived_dim
     (1, T) f32 rows. Mirrors repro.core.enrich.derive_ref: newest entry's
     PER_ENTRY | window mean | window std | newest - mean | nvalid |
-    max hist index | zero pad.
+    max hist index | zero pad. Divisions round as IEEE's (``div_rn``).
     """
     H, T = valid.shape
     stats = [u32_to_f32(word(w)) for w in range(*wire.payload_stats)]
@@ -71,12 +71,15 @@ def derive_rows(word: Callable[[int], jax.Array], valid: jax.Array,
     newest = _hreduce(jnp.minimum, jnp.where(key == top, hpos, H))
     sel = jnp.where(hpos == newest, 1.0, 0.0)     # (H, T) one-hot
     newest_f = [_hsum(f * sel) for f in feats]
-    mean_w = [_hsum(f) / nvalid for f in feats]
+    # the window means and variances each as one (PER_ENTRY, T) division:
+    # whole sublane tiles, and one copy of div_rn, not one per (1, T) row
+    mean = div_rn(jnp.concatenate([_hsum(f) for f in feats]), nvalid)
+    mean_w = [mean[j:j + 1] for j in range(len(feats))]
     # two-pass (masked) variance — same formulation as enrich.derive_ref
-    std_w = []
-    for f, m in zip(feats, mean_w):
-        dev = (f - m) * vmask
-        std_w.append(jnp.sqrt(_hsum(dev * dev) / nvalid))
+    devs = [(f - m) * vmask for f, m in zip(feats, mean_w)]
+    std = jnp.sqrt(div_rn(jnp.concatenate([_hsum(d * d) for d in devs]),
+                          nvalid))
+    std_w = [std[j:j + 1] for j in range(len(feats))]
     delta = [n - m for n, m in zip(newest_f, mean_w)]
     maxhist = _hreduce(jnp.maximum, jnp.where(valid, hist, 0.0))
     rows = newest_f + mean_w + std_w + delta + [nvalid, maxhist]

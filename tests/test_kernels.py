@@ -10,7 +10,7 @@ from repro.kernels.derived_features.ref import derived_features_ref
 from repro.kernels.flow_moments.kernel import (EVENT_BLOCK,
                                                flow_moments_pallas)
 from repro.kernels.flow_moments.ref import flow_moments_ref
-from repro.kernels.ring_scatter.kernel import ring_scatter_pallas
+from repro.kernels.ring_scatter.kernel import CHUNK, ring_scatter_pallas
 from repro.kernels.ring_scatter.ref import ring_scatter_ref
 
 J = jnp.asarray
@@ -52,24 +52,80 @@ def test_flow_moments_all_invalid(rng):
     np.testing.assert_array_equal(np.asarray(got), regs)
 
 
-@pytest.mark.parametrize("F,H,R,tile", [
-    (32, 10, 16, 32), (128, 10, 64, 32), (64, 4, 128, 64),
+def _scatter_coords(rng, layout, F, H, R, tile):
+    """(flow, hist, mask) of R reports laid out as ``layout`` says."""
+    n_tiles = F // tile
+    if layout == "distinct":      # distinct coordinates, random order
+        coords = rng.choice(F * H, size=min(R, F * H), replace=False)
+        flow, hist = coords // H, coords % H
+        mask = rng.random(len(coords)) > 0.2
+    elif layout == "repeats":     # few (flow, hist) of one tile, reused
+        flow = tile + rng.integers(0, 4, size=R) * 7 % tile
+        hist = rng.integers(0, 3, size=R)
+        mask = rng.random(R) > 0.1
+    elif layout == "one_tile":    # every report in one tile
+        flow = (n_tiles - 2) * tile + rng.integers(0, tile, size=R)
+        hist = rng.integers(0, H, size=R)
+        mask = np.ones(R, bool)
+    elif layout == "empty_tiles":  # only tiles 0, 5 and the last
+        t = rng.choice([0, 5, n_tiles - 1], size=R)
+        flow = t * tile + rng.integers(0, tile, size=R)
+        hist = rng.integers(0, H, size=R)
+        mask = rng.random(R) > 0.2
+    elif layout == "all_masked":
+        flow = rng.integers(0, F, size=R)
+        hist = rng.integers(0, H, size=R)
+        mask = np.zeros(R, bool)
+    elif layout == "masked_edges":  # masked rows clipped to flow 0 / F-1
+        flow = rng.integers(0, F, size=R)
+        hist = rng.integers(0, H, size=R)
+        mask = rng.random(R) > 0.5
+        flow[~mask] = np.where(rng.random(int((~mask).sum())) > 0.5,
+                               0, F - 1)
+    else:                         # "over_ring": R > F * H, repeats
+        assert R > F * H
+        flow = rng.integers(0, F, size=R)
+        hist = rng.integers(0, H, size=R)
+        mask = rng.random(R) > 0.2
+    return (np.asarray(flow, np.int32), np.asarray(hist, np.int32),
+            np.asarray(mask, bool))
+
+
+def _last_write_wins(mem, pay, flow, hist, mask):
+    out = mem.copy()
+    for r in np.flatnonzero(mask):
+        out[flow[r], hist[r]] = pay[r]
+    return out
+
+
+@pytest.mark.parametrize("F,H,R,tile,layout", [
+    pytest.param(32, 10, 16, 32, "distinct", id="32-10-16-32"),
+    pytest.param(128, 10, 64, 32, "distinct", id="128-10-64-32"),
+    pytest.param(64, 4, 128, 64, "distinct", id="64-4-128-64"),
+    pytest.param(512, 10, 700, 32, "distinct", id="random_order"),
+    pytest.param(128, 10, 300, 32, "repeats", id="repeats_last_wins"),
+    pytest.param(256, 10, CHUNK + 100, 32, "one_tile", id="one_tile"),
+    pytest.param(512, 10, 400, 32, "empty_tiles", id="empty_tiles"),
+    pytest.param(128, 10, 200, 32, "all_masked", id="all_masked"),
+    pytest.param(128, 10, 300, 32, "masked_edges", id="masked_edges"),
+    pytest.param(512, 10, 2 * CHUNK + 77, 64, "distinct",
+                 id="R_not_chunk_multiple"),
+    pytest.param(32, 4, 300, 16, "over_ring", id="R_over_ring"),
 ])
-def test_ring_scatter_sweep(rng, F, H, R, tile):
+def test_ring_scatter_sweep(rng, F, H, R, tile, layout):
     mem = rng.integers(0, 2**32, size=(F, H, 16),
                        dtype=np.uint64).astype(np.uint32)
-    coords = rng.choice(F * H, size=min(R, F * H), replace=False)
-    R = len(coords)
-    flow = (coords // H).astype(np.int32)
-    hist = (coords % H).astype(np.int32)
+    flow, hist, mask = _scatter_coords(rng, layout, F, H, R, tile)
+    R = len(flow)
     pay = rng.integers(0, 2**32, size=(R, 16),
                        dtype=np.uint64).astype(np.uint32)
     pay[:, 0] = np.maximum(pay[:, 0], 1)
-    mask = rng.random(R) > 0.2
     got = ring_scatter_pallas(mem, pay, flow, hist, mask, flow_tile=tile,
                               history=H)
     want = ring_scatter_ref(J(mem), J(pay), J(flow), J(hist), J(mask))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(got), _last_write_wins(mem, pay, flow, hist, mask))
 
 
 def test_ring_scatter_duplicate_order(rng):

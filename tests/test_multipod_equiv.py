@@ -408,3 +408,37 @@ def test_home_assignment_matches_translator():
     got = set(np.asarray(fid)[np.asarray(em)].tolist())
     assert got <= expect
     assert got, "no flows routed"
+
+
+@pytest.mark.parametrize("scenario", ["elephants_mice", "collision_storm"])
+def test_pod_mesh_pallas_kernels_match_ref(scenario):
+    """The (2, 2) mesh with the Pallas kernels (interpret) against the
+    ref backend. The home side's canonical (flow, reporter, seq) order
+    puts several reports of one flow, from different ports, in one
+    batch, each placed in its own history entry. End state, every
+    per-period metric and flow id bitwise; features within 1e-5 of their
+    row's scale (the tolerance of test_gather_enrich_equiv)."""
+    mesh = pod_mesh_or_skip(2, 2)
+    sysm = DFASystem(dataclasses.replace(_mesh_cfg(2, 2, "none",
+                                                   TOTAL_PORTS),
+                                         kernel_backend="interpret"), mesh)
+    events, nows = _trace(scenario)
+    with sysm.mesh:
+        out = jax.jit(sysm.run_periods)(sysm.init_state(), events, nows)
+    rst, rout, rmet = _run(2, 2, "none", False, scenario)
+    gst = _merged_state(sysm, out.state)
+    for k in rst:
+        np.testing.assert_array_equal(gst[k], rst[k], err_msg=k)
+    for k in rmet:
+        np.testing.assert_array_equal(np.asarray(out.metrics[k]), rmet[k],
+                                      err_msg=k)
+    gout = _canon_periods(out.enriched, out.flow_ids, out.mask)
+    for t, (g, r) in enumerate(zip(gout, rout)):
+        np.testing.assert_array_equal(g["fid"], r["fid"], f"period {t}")
+        want = r["enr"]
+        fin = np.isfinite(want)
+        np.testing.assert_array_equal(np.isfinite(g["enr"]), fin)
+        want = np.where(fin, want, 0.0)
+        scale = np.maximum(1.0, np.abs(want).max(axis=-1, keepdims=True))
+        err = np.abs(np.where(fin, g["enr"], 0.0) - want) / scale
+        assert err.max(initial=0.0) <= 1e-5, (t, err.max())

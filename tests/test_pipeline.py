@@ -1,5 +1,7 @@
 """End-to-end DFA pipeline: packets -> registers -> reports -> routing ->
 ring memory -> enriched features, validated against ground truth."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -106,3 +108,49 @@ def test_metrics_are_conserved(system):
     drop = int(metrics["bucket_drops"])
     assert sent == recv + drop
     assert recv == int(np.asarray(emask).sum())
+
+
+def test_every_period_ring_and_features_pallas_vs_ref():
+    """Pallas kernels (interpret) against the ref backend after EVERY
+    period, not only at the end: the collector's ring and validity
+    bitwise, the delivered features within 1e-5 of their row's scale (the
+    tolerance of test_gather_enrich_equiv). 200 flows through 128 report
+    slots over 14 periods: every flow tile is written from period 0, the
+    reports due outnumber the slots from period 6, and flows fill all 10
+    history entries of their ring and wrap."""
+    cfg = get_dfa_config(reduced=True)
+    mesh = make_mesh((1, 1), ("data", "model"))
+    events, nows = PK.period_batches(1, 14, cfg.event_block, n_flows=200,
+                                     flow_seed=3)
+    runs = {}
+    for backend in ("interpret", "ref"):
+        system = DFASystem(dataclasses.replace(cfg, kernel_backend=backend),
+                           mesh)
+        step = system.jit_step(donate=False)
+        state, periods = system.init_state(), []
+        with system.mesh:
+            for t in range(nows.shape[0]):
+                out = step(state, {k: v[t] for k, v in events.items()},
+                           nows[t])
+                state = out.state
+                periods.append(jax.device_get(
+                    (state.collector.memory, state.collector.entry_valid,
+                     out.mask, out.enriched, out.metrics["reports_due"])))
+        runs[backend] = periods
+    full_rings, tiles = 0, cfg.flows_per_shard // cfg.flow_tile
+    for t, (got, want) in enumerate(zip(runs["interpret"], runs["ref"])):
+        mem, ev, mask, feat, due = got
+        np.testing.assert_array_equal(mem, want[0], err_msg=f"period {t}")
+        np.testing.assert_array_equal(ev, want[1], err_msg=f"period {t}")
+        np.testing.assert_array_equal(mask, want[2], err_msg=f"period {t}")
+        fin = np.isfinite(want[3][mask])
+        np.testing.assert_array_equal(np.isfinite(feat[mask]), fin)
+        got_f = np.where(fin, feat[mask], 0.0)
+        want_f = np.where(fin, want[3][mask], 0.0)
+        scale = np.maximum(1.0, np.abs(want_f).max(axis=-1, keepdims=True))
+        err = np.abs(got_f - want_f) / scale
+        assert err.max(initial=0.0) <= 1e-5, (t, err.max())
+        assert ev.reshape(tiles, -1).any(axis=1).all()
+        assert (t < 6) or (int(due) > int(mask.sum()) == cfg.report_capacity)
+        full_rings = int(ev.all(axis=1).sum())
+    assert full_rings > 50

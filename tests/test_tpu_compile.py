@@ -80,12 +80,18 @@ def _ingest_hbm(s):
         + [s((E,), jnp.uint32)] * 3 + [s((E,), jnp.int32)])
 
 
-def _ring_scatter(s):
+def _ring_scatter(s, R=R):
     return (lambda m, p, f, h, k: RS.ring_scatter_pallas(
         m, p, f, h, k, flow_tile=PAPER.flow_tile, history=H,
         interpret=False),
         [s((F, H, 16), jnp.uint32), s((R, 16), jnp.uint32),
          s((R,), jnp.int32), s((R,), jnp.int32), s((R,), jnp.bool_)])
+
+
+def _ring_scatter_2r(s):
+    """Twice the PAPER reports: VMEM holds the ring tile and two payload
+    chunks, so it no longer bounds R."""
+    return _ring_scatter(s, R=2 * R)
 
 
 def _gather_hbm(s):
@@ -109,10 +115,12 @@ def _gather_full(s):
     (_ingest_block, "ingest_update_block"),
     (_ingest_hbm, "ingest_update_hbm"),
     (_ring_scatter, "ring_scatter"),
+    (_ring_scatter_2r, "ring_scatter"),
     (_gather_hbm, "gather_enrich_hbm"),
     (_gather_full, "gather_enrich_full"),
 ], ids=["ingest_block_E1024", "ingest_hbm_E2^20", "ring_scatter_F2^17",
-        "gather_enrich_hbm_F2^17", "gather_enrich_full_F2^12"])
+        "ring_scatter_F2^17_R2^17", "gather_enrich_hbm_F2^17",
+        "gather_enrich_full_F2^12"])
 def test_main_path_kernel_compiles_for_v5e(one_chip, build, kernel):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
